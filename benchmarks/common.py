@@ -2,10 +2,12 @@
 
 Two layers live here:
 
-  * **Device/topology CLI flags** (``add_device_args`` / ``configure_devices``):
-    the one place ``--devices`` / ``--node-shards`` / fake-host XLA_FLAGS
-    forcing is parsed, shared by ``benchmarks/run.py``,
-    ``scripts/dev_smoke.py`` and ``scripts/perf_gate.py``.  Forcing fake
+  * **Process set-up**, shared by ``chip_smoke.py``, ``benchmarks/run.py``,
+    ``scripts/dev_smoke.py`` and ``scripts/perf_gate.py``: the persistent
+    compile cache (``configure_compile_cache``) and the device/topology CLI
+    flags (``add_device_args`` / ``configure_devices``), the one place
+    ``--devices`` / ``--node-shards`` / fake-host XLA_FLAGS forcing is
+    parsed.  Forcing fake
     host devices must happen BEFORE jax is imported, so this module keeps
     its import surface jax-free — every heavy import below is local to the
     function that needs it.
@@ -80,6 +82,28 @@ def configure_devices(args, *, error=None) -> int:
         ).strip()
     NODE_SHARDS = args.node_shards or None
     return n_dev
+
+
+# JAX's persistent compilation cache lives here unless JAX_COMPILATION_CACHE_DIR
+# says otherwise.  A fixed path: the directory is part of the cache key, so a
+# path built from a temp name, pid or time would never hit.
+COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))), ".jax_cache"
+)
+
+
+def configure_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; call before the first
+    compile.  ``JAX_COMPILATION_CACHE_DIR``, when set, is read by JAX itself
+    and no other directory is set; otherwise the cache goes to
+    :data:`COMPILE_CACHE_DIR`.  Returns the directory in use."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    import jax
+
+    jax.config.update("jax_compilation_cache_dir", COMPILE_CACHE_DIR)
+    return COMPILE_CACHE_DIR
 
 
 def split_knobs(kw: Dict) -> Tuple[Dict, Dict]:

@@ -1,4 +1,4 @@
-"""Benchmark entry point: one module per paper figure/table + roofline.
+"""Benchmark entry point: one module per paper figure/table.
 
 Default mode keeps sizes CI-friendly (single CPU core); ``--full`` runs the
 paper-scale sweeps.  Output: CSV lines prefixed by figure id.
@@ -30,7 +30,6 @@ BENCHMARKS = {
     "qp_scaling": "qp_scaling",
     "hybrid": "hybrid_search",
     "mvcc_slots": "mvcc_slots",
-    "roofline": "roofline",
 }
 
 
@@ -51,6 +50,7 @@ def main() -> None:
     # shared --node-shards/--devices handling (fake-host XLA_FLAGS forcing
     # must precede the first jax import, which the benchmark modules do)
     common.configure_devices(args, error=ap.error)
+    common.configure_compile_cache()
 
     import importlib
 
@@ -63,10 +63,7 @@ def main() -> None:
         if want and name not in want:
             continue
         print(f"# === {name} ({time.time()-t0:.0f}s elapsed) ===", flush=True)
-        try:
-            mod.main(full=args.full)
-        except FileNotFoundError as e:
-            print(f"# {name}: skipped ({e})")
+        mod.main(full=args.full)
     print(f"# all benchmarks done in {time.time()-t0:.0f}s")
 
 

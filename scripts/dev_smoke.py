@@ -133,7 +133,7 @@ def main(arch_ids):
 
 
 if __name__ == "__main__":
-    from benchmarks.common import add_device_args, configure_devices
+    from benchmarks.common import add_device_args, configure_compile_cache, configure_devices
 
     ap = argparse.ArgumentParser()
     ap.add_argument("arch_ids", nargs="*", help="LM arch ids (default: all)")
@@ -144,6 +144,7 @@ if __name__ == "__main__":
     add_device_args(ap)
     args = ap.parse_args()
     configure_devices(args, error=ap.error)
+    configure_compile_cache()
     print(f"--- protocol matrix ({'batched' if args.fast else 'sequential'})", flush=True)
     protocol_matrix(args.fast)
     if not args.skip_lm:

@@ -48,13 +48,11 @@ for _p in (_ROOT, os.path.join(_ROOT, "src")):
     if _p not in sys.path:
         sys.path.insert(0, _p)
 
-from benchmarks.common import add_device_args, configure_devices  # jax-free
-
-
-def _measured_delta(before: dict, after: dict, cache: str):
-    if before[cache] < 0 or after[cache] < 0:
-        return None  # no introspection in this JAX version
-    return after[cache] - before[cache]
+from benchmarks.common import (  # jax-free
+    add_device_args,
+    configure_compile_cache,
+    configure_devices,
+)
 
 
 def gate_hybrid_enumeration(budget_s: float) -> None:
@@ -76,17 +74,13 @@ def gate_hybrid_enumeration(budget_s: float) -> None:
     rows = api.execute(pl).rows
     wall = time.time() - t0
     assert len(rows) == 64 and all(r["commits"] > 0 for r in rows), "sweep produced bad rows"
-    delta = _measured_delta(before, api.compile_stats(), pl.cache)
-    if delta is not None:
-        assert delta == pl.expected_compiles, (
-            f"2^6 hybrid enumeration compiled {delta} programs "
-            f"(planner budgeted {pl.expected_compiles}): a static/traced knob split regression"
-        )
-    assert wall < budget_s, f"hybrid enumeration took {wall:.1f}s (budget {budget_s:.0f}s)"
-    compiles = (
-        f"{delta} compile(s)" if delta is not None else "compile count UNCHECKED (no introspection)"
+    delta = api.compile_stats()[pl.cache] - before[pl.cache]
+    assert delta == pl.expected_compiles, (
+        f"2^6 hybrid enumeration compiled {delta} programs "
+        f"(planner budgeted {pl.expected_compiles}): a static/traced knob split regression"
     )
-    print(f"perf gate ok: 64-coding sweep = {compiles}, {wall:.1f}s < {budget_s:.0f}s budget")
+    assert wall < budget_s, f"hybrid enumeration took {wall:.1f}s (budget {budget_s:.0f}s)"
+    print(f"perf gate ok: 64-coding sweep = {delta} compile(s), {wall:.1f}s < {budget_s:.0f}s budget")
     return {"wall_s": round(wall, 3), "compiles": delta, "budget_s": budget_s}
 
 
@@ -113,20 +107,16 @@ def gate_bucketed_coroutines(budget_s: float) -> None:
     assert all(r["commits"] > 0 for r in rows), "bucketed sweep produced bad rows"
     assert [r["coroutines"] for r in rows] == [10, 12, 14, 16]
     assert rows[0]["n_buckets"] == 1
-    delta = _measured_delta(before, api.compile_stats(), pl.cache)
-    if delta is not None:
-        assert delta == pl.expected_compiles, (
-            f"bucketed co-routine sweep compiled {delta} programs "
-            f"(planner budgeted {pl.expected_compiles} for {len(spec.configs)} configs): "
-            "the bucketing planner or active-extent knobs regressed"
-        )
-        compiles = f"{delta} compile(s)"
-    else:
-        compiles = "compile count UNCHECKED (no introspection)"
+    delta = api.compile_stats()[pl.cache] - before[pl.cache]
+    assert delta == pl.expected_compiles, (
+        f"bucketed co-routine sweep compiled {delta} programs "
+        f"(planner budgeted {pl.expected_compiles} for {len(spec.configs)} configs): "
+        "the bucketing planner or active-extent knobs regressed"
+    )
     assert wall < budget_s, f"bucketed co-routine sweep took {wall:.1f}s (budget {budget_s:.0f}s)"
     print(
         f"perf gate ok: 4-point co-routine sweep = 1 bucket, "
-        f"{compiles}, {wall:.1f}s < {budget_s:.0f}s budget"
+        f"{delta} compile(s), {wall:.1f}s < {budget_s:.0f}s budget"
     )
     return {"wall_s": round(wall, 3), "compiles": delta, "budget_s": budget_s}
 
@@ -156,19 +146,15 @@ def gate_node_sharded_tick(budget_s: float) -> None:
     rows = [api.execute(pl).row for pl in plans]
     wall = time.time() - t0
     assert all(r["commits"] > 0 for r in rows), "node-sharded cells produced bad rows"
-    delta = _measured_delta(before, api.compile_stats(), "node")
-    if delta is not None:
-        # expected_compiles is a cold-cache bound per plan; the three plans
-        # share one (GridSpec, mesh) program, so the measured total is 1
-        assert delta == 1, (
-            f"node-sharded tick compiled {delta} programs for 3 configs on one mesh "
-            "(want 1): a knob leaked into the compiled program structure"
-        )
-        compiles = f"{delta} compile(s)"
-    else:
-        compiles = "compile count UNCHECKED (no introspection)"
+    delta = api.compile_stats()["node"] - before["node"]
+    # expected_compiles is a cold-cache bound per plan; the three plans
+    # share one (GridSpec, mesh) program, so the measured total is 1
+    assert delta == 1, (
+        f"node-sharded tick compiled {delta} programs for 3 configs on one mesh "
+        "(want 1): a knob leaked into the compiled program structure"
+    )
     assert wall < budget_s, f"node-sharded cells took {wall:.1f}s (budget {budget_s:.0f}s)"
-    print(f"perf gate ok: 3 node-sharded configs = {compiles}, {wall:.1f}s < {budget_s:.0f}s budget")
+    print(f"perf gate ok: 3 node-sharded configs = {delta} compile(s), {wall:.1f}s < {budget_s:.0f}s budget")
     return {"wall_s": round(wall, 3), "compiles": delta, "budget_s": budget_s}
 
 
@@ -299,6 +285,7 @@ if __name__ == "__main__":
     add_device_args(ap)
     args = ap.parse_args()
     configure_devices(args, error=ap.error)
+    configure_compile_cache()
     main(
         args.budget,
         args.bucket_budget,

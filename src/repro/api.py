@@ -449,10 +449,8 @@ def execute(pl: ExecutionPlan) -> Results:
                 ticks_active=jnp.asarray(np.array(b.ticks_active, np.int32))
             )
         t0 = time.time()
-        if pl.layout == CONFIG_NODE:
-            out = _sweep._run_sharded_2d(gs, knobs, list(pl.devices), pl.node_shards)
-        elif pl.layout == CONFIG:
-            out = _sweep._run_sharded(gs, knobs, list(pl.devices))
+        if pl.layout in (CONFIG, CONFIG_NODE):
+            out = _sweep._run_sharded(gs, knobs, list(pl.devices), pl.node_shards)
         else:
             if pl.devices is not None:  # honor an explicit single-device placement
                 knobs = jax.device_put(knobs, pl.devices[0])
@@ -504,9 +502,9 @@ def run(spec: ExperimentSpec) -> Results:
 
 
 def compile_stats() -> Dict[str, int]:
-    """Programs compiled so far per jit cache (-1 = no introspection in this
-    JAX version).  Keys match :attr:`ExecutionPlan.cache`; perf_gate asserts
-    the measured deltas against ``ExecutionPlan.expected_compiles``."""
+    """Programs compiled so far per jit cache.  Keys match
+    :attr:`ExecutionPlan.cache`; perf_gate asserts the measured deltas
+    against ``ExecutionPlan.expected_compiles``."""
     return {
         "grid": _sweep.compile_cache_size(),
         "grid_sharded": _sweep.sharded_compile_cache_size(),

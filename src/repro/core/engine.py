@@ -673,7 +673,7 @@ def run_sharded(
         return run(protocol_tick, ec_sh, cm, wl, n_ticks, warmup=warmup)
 
     return planes.shard_map(
-        body, mesh=mesh, in_specs=(), out_specs=(P(), P(axis), P()), check_rep=False
+        body, mesh=mesh, in_specs=(), out_specs=(P(), P(axis), P())
     )()
 
 

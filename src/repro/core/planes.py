@@ -35,15 +35,19 @@ from typing import Callable, NamedTuple, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
-try:
-    from jax import shard_map  # type: ignore
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map  # type: ignore
-
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core.arbiter import scatter_min_winner
 from repro.kernels import ops as kops
+
+
+def shard_map(f, *, mesh: Mesh, in_specs, out_specs):
+    """``jax.shard_map`` with replication checking off: the one place every
+    SPMD body of this repo (engine, CALVIN, 2-D grid, routed planes) enters
+    a mesh.  The engine's replicated outputs are psum'd or sequencer-
+    replicated by construction, which the checker cannot see through a
+    scan carry."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False)
 
 
 # ---------------------------------------------------------------------------

@@ -204,7 +204,7 @@ def run_epochs_sharded(
         return run_epochs(ec_sh, cm, wl, n_epochs, epochs_active=epochs_active)
 
     return planes.shard_map(
-        body, mesh=mesh, in_specs=(), out_specs=(P(axis), P()), check_rep=False
+        body, mesh=mesh, in_specs=(), out_specs=(P(axis), P())
     )()
 
 

@@ -34,7 +34,7 @@ Two scale-out layers sit on top (DESIGN.md §6):
     instead of one per distinct shape, with padded slots/records provably
     inert (bitwise-equal counters to the unpadded run).
   * **Device sharding**: ``run_grid_sharded`` splits the config axis over
-    ``jax.sharding`` (a 1-D ``grid`` mesh).  Grids that don't divide the
+    a 1-D ``grid`` mesh (``planes.shard_map``).  Grids that don't divide the
     device count are remainder-padded (the pad rows replicate the last
     config and are dropped on output), so any grid size works on any
     device count — real devices or ``--xla_force_host_platform_device_count``
@@ -50,7 +50,7 @@ from typing import Any, Dict, Iterable, List, NamedTuple, Optional, Sequence, Tu
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec
+from jax.sharding import Mesh, PartitionSpec
 
 from repro.core import registry
 from repro.core.costmodel import N_HYBRID_STAGES, RPC, CostModel
@@ -215,38 +215,19 @@ def _run_grid_jit(spec: GridSpec, knobs: RunKnobs) -> Dict:
     return jax.vmap(functools.partial(_run_one, spec))(knobs)
 
 
-@functools.partial(jax.jit, static_argnums=0)
-def _run_grid_sharded_jit(spec: GridSpec, knobs: RunKnobs) -> Dict:
-    # identical program to _run_grid_jit; a separate jit entry so the two
-    # compile counters stay independent (the sharded path recompiles per
-    # input sharding, which would pollute the single-compile perf gate)
-    return jax.vmap(functools.partial(_run_one, spec))(knobs)
-
-
 def compile_cache_size() -> int:
-    """Number of distinct programs compiled for run_grid so far (-1 if the
-    introspection API is unavailable in this JAX version)."""
-    try:
-        return _run_grid_jit._cache_size()
-    except Exception:
-        return -1
+    """Number of distinct programs compiled for run_grid so far."""
+    return _run_grid_jit._cache_size()
 
 
 def sharded_compile_cache_size() -> int:
-    """Compile count of the device-sharded entry point (-1 = no introspection)."""
-    try:
-        return _run_grid_sharded_jit._cache_size()
-    except Exception:
-        return -1
+    """Programs compiled by the config-axis (1-D ``grid`` mesh) runners."""
+    return sum(fn._cache_size() for (_, _, ns), fn in _GRID_RUNNERS.items() if ns is None)
 
 
 def grid2d_compile_count() -> int:
-    """Programs compiled by the 2-D ``config × node`` runners so far (-1 if
-    the introspection API is unavailable)."""
-    try:
-        return sum(fn._cache_size() for fn in _GRID2D_RUNNERS.values())
-    except Exception:
-        return -1
+    """Programs compiled by the 2-D ``config × node`` runners so far."""
+    return sum(fn._cache_size() for (_, _, ns), fn in _GRID_RUNNERS.items() if ns is not None)
 
 
 def _warn_legacy(name: str) -> None:
@@ -350,89 +331,74 @@ def plan_buckets(
     return buckets
 
 
-def _run_sharded(spec: GridSpec, knobs: RunKnobs, devices) -> Dict:
-    """Dispatch one bucket's grid with the config axis sharded over devices.
+# (GridSpec, device-key, node_shards or None) -> jitted mesh grid runner
+_GRID_RUNNERS: Dict[Tuple[GridSpec, Tuple[str, ...], Optional[int]], Any] = {}
 
-    Pads the grid to a multiple of the device count by replicating the last
-    config (the pad rows are sliced off the output — they never reach a
-    caller), lays the knob pytree out with a 1-D ``grid`` mesh sharding,
-    and lets jit partition the vmapped program over it.
+
+def _grid_runner(spec: GridSpec, devices: Sequence, node_shards: Optional[int]):
+    """One jitted ``shard_map`` of the vmapped grid over a device mesh.
+
+    ``node_shards=None``: a 1-D ``grid`` mesh, each device running its
+    slice of the configs dense.  Otherwise a 2-D ``config × node`` mesh on
+    which each config's simulation also runs node-sharded.  The grid enters
+    the mesh through ``shard_map`` rather than jit's automatic
+    partitioning, which cannot partition the Pallas kernels.
     """
-    n_dev = len(devices)
-    size = int(np.asarray(knobs.seed).shape[0])
-    pad = (-size) % n_dev
-    if pad:
-        knobs = jax.tree_util.tree_map(
-            lambda x: jnp.concatenate([x, jnp.repeat(x[-1:], pad, axis=0)], axis=0), knobs
-        )
-    mesh = Mesh(np.asarray(devices), ("grid",))
-    knobs = jax.device_put(knobs, NamedSharding(mesh, PartitionSpec("grid")))
-    out = _run_grid_sharded_jit(spec, knobs)
-    return {k: np.asarray(v)[:size] for k, v in out.items()}
-
-
-# (GridSpec, device-key, node_shards) -> jitted 2-D grid runner
-_GRID2D_RUNNERS: Dict[Tuple[GridSpec, Tuple[str, ...], int], Any] = {}
-
-
-def _grid2d_runner(spec: GridSpec, devices: Sequence, node_shards: int):
     key = (spec, tuple(str(d) for d in devices), node_shards)
-    fn = _GRID2D_RUNNERS.get(key)
+    fn = _GRID_RUNNERS.get(key)
     if fn is not None:
         return fn
-    from jax.sharding import PartitionSpec as P
-
     from repro.core import planes
 
-    n_cfg = len(devices) // node_shards
-    mesh = Mesh(np.asarray(list(devices)).reshape(n_cfg, node_shards), ("grid", "node"))
-    shard = planes.NodeShard(axis="node", n_shards=node_shards)
-
-    @jax.jit
-    def runner(knobs: RunKnobs) -> Dict:
-        def body(kn_local):
-            return jax.vmap(functools.partial(_run_one, spec, shard=shard))(kn_local)
-
-        return planes.shard_map(
-            body, mesh=mesh, in_specs=(P("grid"),), out_specs=P("grid"), check_rep=False
-        )(knobs)
-
-    _GRID2D_RUNNERS[key] = runner
+    if node_shards is None:
+        mesh, shard = Mesh(np.asarray(list(devices)), ("grid",)), None
+    else:
+        n_cfg = len(devices) // node_shards
+        mesh = Mesh(np.asarray(list(devices)).reshape(n_cfg, node_shards), ("grid", "node"))
+        shard = planes.NodeShard(axis="node", n_shards=node_shards)
+    body = jax.vmap(functools.partial(_run_one, spec, shard=shard))
+    runner = jax.jit(
+        planes.shard_map(body, mesh=mesh, in_specs=(PartitionSpec("grid"),),
+                         out_specs=PartitionSpec("grid"))
+    )
+    _GRID_RUNNERS[key] = runner
     return runner
 
 
-def _run_sharded_2d(spec: GridSpec, knobs: RunKnobs, devices, node_shards: int) -> Dict:
-    """Dispatch one bucket's grid on a 2-D ``config × node`` mesh.
+def _run_sharded(
+    spec: GridSpec, knobs: RunKnobs, devices, node_shards: Optional[int] = None
+) -> Dict:
+    """Dispatch one bucket's grid with the config axis sharded over devices.
 
-    The config axis splits over the mesh's ``grid`` axis exactly as
-    :func:`_run_sharded`; each config's SIMULATION additionally runs
-    node-sharded over the ``node`` axis (every plane exchange inside the
-    vmapped engine batches over the local configs).  One ``shard_map``
-    covers both axes, so the composition is a mesh-construction choice —
-    the engine program is the same one :func:`~repro.core.engine.run_sharded`
-    runs on a 1-D node mesh.
+    Pads the grid to a multiple of the config-axis size by replicating the
+    last config (the pad rows are sliced off the output — they never reach
+    a caller).  With ``node_shards`` the mesh is 2-D ``config × node``:
+    each config's SIMULATION additionally runs node-sharded over the
+    ``node`` axis (every plane exchange inside the vmapped engine batches
+    over the local configs), the same engine program
+    :func:`~repro.core.engine.run_sharded` runs on a 1-D node mesh.
     """
-    entry = registry.get_protocol(spec.protocol)
-    if not entry.caps.batch_node_shardable:
-        # e.g. calvin: the wave executor iterates a per-config traced wave
-        # count — configs cannot batch around its node collectives
-        raise ValueError(
-            f"protocol {spec.protocol!r} cannot run on a 2-D config × node mesh: "
-            "its registry entry sets Caps(batch_node_shardable=False); shard the "
-            "config axis only (node_shards=None)"
-        )
-    if spec.n_nodes % node_shards:
-        raise ValueError(
-            f"node_shards={node_shards} must divide n_nodes={spec.n_nodes}"
-        )
-    n_cfg = len(devices) // node_shards
+    if node_shards is not None:
+        if not registry.get_protocol(spec.protocol).caps.batch_node_shardable:
+            # e.g. calvin: the wave executor iterates a per-config traced wave
+            # count — configs cannot batch around its node collectives
+            raise ValueError(
+                f"protocol {spec.protocol!r} cannot run on a 2-D config × node mesh: "
+                "its registry entry sets Caps(batch_node_shardable=False); shard the "
+                "config axis only (node_shards=None)"
+            )
+        if spec.n_nodes % node_shards:
+            raise ValueError(
+                f"node_shards={node_shards} must divide n_nodes={spec.n_nodes}"
+            )
+    n_cfg = len(devices) // (node_shards or 1)
     size = int(np.asarray(knobs.seed).shape[0])
     pad = (-size) % n_cfg
     if pad:
         knobs = jax.tree_util.tree_map(
             lambda x: jnp.concatenate([x, jnp.repeat(x[-1:], pad, axis=0)], axis=0), knobs
         )
-    out = _grid2d_runner(spec, devices, node_shards)(knobs)
+    out = _grid_runner(spec, devices, node_shards)(knobs)
     return {k: np.asarray(v)[:size] for k, v in out.items()}
 
 
@@ -574,13 +540,10 @@ def _node_runner(spec: GridSpec, devices: Sequence):
 
 
 def node_sharded_compile_count() -> int:
-    """Programs compiled by the node-sharded runners so far (-1 if the
-    introspection API is unavailable): one per (GridSpec, mesh) pair when
-    the knob tracing holds, regardless of how many configs ran."""
-    try:
-        return sum(fn._cache_size() for fn in _NODE_RUNNERS.values())
-    except Exception:
-        return -1
+    """Programs compiled by the node-sharded runners so far: one per
+    (GridSpec, mesh) pair when the knob tracing holds, regardless of how
+    many configs ran."""
+    return sum(fn._cache_size() for fn in _NODE_RUNNERS.values())
 
 
 def run_cell_sharded(

@@ -1,11 +1,12 @@
 """Lock-CAS arbitration — Pallas TPU kernel.
 
 Models the owning node's RNIC serializing concurrent CAS verbs: within each
-owner's request block, request i wins iff no active request j on the same
-key has a lexicographically smaller (prio_hi, prio_lo).  Requests are
-grouped per owning node (the grid axis), so arbitration is all-pairs within
-a (block_m x block_m) VPU tile — the TPU-native replacement for the
-GPU-style atomic-CAS loop.
+owner group, request i wins iff no active request j on the same key has a
+lexicographically smaller (prio_hi, prio_lo).  The all-pairs compare is
+tiled over a (G, i-block, j-block) grid: each step compares one block of
+contenders j (sublanes) against one block of requests i (lanes) and ORs a
+lane-dense "beaten" flag, so no tile grows with the batch — the
+TPU-native replacement for the GPU-style atomic-CAS loop.
 
 Semantics are EXACTLY ``repro.core.arbiter.scatter_min_winner``: pure
 lexicographic minimum, no index tiebreak — engine callers guarantee unique
@@ -24,21 +25,24 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 
-def _kernel(keys_ref, hi_ref, lo_ref, active_ref, won_ref):
-    keys = keys_ref[0]  # (bm,)
-    hi = hi_ref[0]
-    lo = lo_ref[0]
-    act = active_ref[0]
-    same = keys[:, None] == keys[None, :]
-    beats_me = (
-        same
-        & act[None, :]
-        & ((hi[None, :] < hi[:, None]) | ((hi[None, :] == hi[:, None]) & (lo[None, :] < lo[:, None])))
+def _kernel(key_i_ref, hi_i_ref, lo_i_ref, key_j_ref, hi_j_ref, lo_j_ref, act_j_ref, beaten_ref):
+    @pl.when(pl.program_id(2) == 0)
+    def _zero():
+        beaten_ref[...] = jnp.zeros_like(beaten_ref)
+
+    key_i, hi_i, lo_i = key_i_ref[...], hi_i_ref[...], lo_i_ref[...]  # (1, b): requests
+    key_j, hi_j, lo_j = key_j_ref[...], hi_j_ref[...], lo_j_ref[...]  # (b, 1): contenders
+    beats = (
+        (key_j == key_i)
+        & (act_j_ref[...] != 0)
+        & ((hi_j < hi_i) | ((hi_j == hi_i) & (lo_j < lo_i)))
+    )  # (b, b)
+    beaten_ref[...] = jnp.maximum(
+        beaten_ref[...], beats.astype(jnp.int32).max(axis=0, keepdims=True)
     )
-    won_ref[0] = act & ~beats_me.any(axis=1)
 
 
-def lock_arbiter(keys, prio_hi, prio_lo, active, *, block_m: int | None = None, interpret=None):
+def lock_arbiter(keys, prio_hi, prio_lo, active, *, block_m: int = 512, interpret=None):
     """Per-owner arbitration. keys/prio_hi/prio_lo (G, M) int32, active
     (G, M) bool -> won (G, M) bool.  G = owner groups (nodes); M = max
     requests per owner.  A request wins iff it is the per-key lexicographic
@@ -49,27 +53,24 @@ def lock_arbiter(keys, prio_hi, prio_lo, active, *, block_m: int | None = None, 
 
         interpret = ops.default_interpret()
     G, M = keys.shape
-    if block_m is None:
-        block_m = max(128, 1 << (M - 1).bit_length())
+    block_m = min(block_m, pl.cdiv(M, 128) * 128)
     pad = (-M) % block_m
-    if pad:
-        keys = jnp.pad(keys, ((0, 0), (0, pad)), constant_values=-1)
-        prio_hi = jnp.pad(prio_hi, ((0, 0), (0, pad)))
-        prio_lo = jnp.pad(prio_lo, ((0, 0), (0, pad)))
-        active = jnp.pad(active, ((0, 0), (0, pad)))
+    act = active.astype(jnp.int32)
+    keys, prio_hi, prio_lo, act = (
+        jnp.pad(a, ((0, 0), (0, pad))) for a in (keys, prio_hi, prio_lo, act)
+    )
     Mp = M + pad
-    assert Mp == block_m, "per-owner request count must fit one arbitration tile"
-    won = pl.pallas_call(
+    n = Mp // block_m
+    rows = [a[:, None, :] for a in (keys, prio_hi, prio_lo)]  # (G, 1, Mp)
+    cols = [a[:, :, None] for a in (keys, prio_hi, prio_lo, act)]  # (G, Mp, 1)
+    row_spec = pl.BlockSpec((None, 1, block_m), lambda g, i, j: (g, 0, i))
+    col_spec = pl.BlockSpec((None, block_m, 1), lambda g, i, j: (g, j, 0))
+    beaten = pl.pallas_call(
         _kernel,
-        grid=(G,),
-        in_specs=[
-            pl.BlockSpec((1, Mp), lambda g: (g, 0)),
-            pl.BlockSpec((1, Mp), lambda g: (g, 0)),
-            pl.BlockSpec((1, Mp), lambda g: (g, 0)),
-            pl.BlockSpec((1, Mp), lambda g: (g, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, Mp), lambda g: (g, 0)),
-        out_shape=jax.ShapeDtypeStruct((G, Mp), jnp.bool_),
+        grid=(G, n, n),
+        in_specs=[row_spec] * 3 + [col_spec] * 4,
+        out_specs=row_spec,
+        out_shape=jax.ShapeDtypeStruct((G, 1, Mp), jnp.int32),
         interpret=interpret,
-    )(keys, prio_hi, prio_lo, active)
-    return won[:, :M]
+    )(*rows, *cols)
+    return active & (beaten[:, 0, :M] == 0)

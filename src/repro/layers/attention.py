@@ -12,15 +12,11 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ArchConfig
 from repro.sharding import AxisRules, dense_init, zeros_init
-
-try:  # jax>=0.6 moved shard_map to jax.shard_map
-    from jax import shard_map  # type: ignore
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map  # type: ignore
 
 
 # ---------------------------------------------------------------------------

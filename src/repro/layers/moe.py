@@ -13,16 +13,12 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ArchConfig
 from repro.layers.common import activation
 from repro.sharding import AxisRules, dense_init
-
-try:
-    from jax import shard_map  # type: ignore
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map  # type: ignore
 
 
 def init_moe(key, cfg: ArchConfig, dtype=jnp.float32):

@@ -16,6 +16,7 @@ from typing import Any, Dict, Optional
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 
 from repro import flags
 from jax.sharding import PartitionSpec as P
@@ -28,11 +29,6 @@ from repro.layers.moe import apply_moe, init_moe
 from repro.layers.rglru import apply_rglru, init_rglru
 from repro.layers.ssm import apply_ssm, init_ssm
 from repro.sharding import AxisRules, Param, dense_init, name_key, unzip_params
-
-try:
-    from jax import shard_map  # type: ignore
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map  # type: ignore
 
 
 # ---------------------------------------------------------------------------

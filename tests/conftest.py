@@ -1,5 +1,7 @@
 import os
 import sys
 
-# tests must see 1 device (the dry-run sets 512 in its own process only)
+# tests run on the CPU backend (Pallas kernels in interpret mode) and never
+# take an attached accelerator; a caller may still pick another platform
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))

@@ -1,0 +1,84 @@
+"""The engine's Pallas kernels compile for a TPU v5e at paper-scale shapes.
+
+Nothing runs: each kernel is lowered and compiled for one chip of a
+described (not attached) ``v5e:2x2`` topology, which raises what the chip's
+compiler would raise (unsupported layouts, VMEM overuse).  Shapes come from
+the api's paper-scale defaults: 4 nodes x 60 co-routines x 10 YCSB ops =
+2,400 requests against 4 x 65,536 = 262,144 records, and TPC-C's 15 ops =
+3,600 lock requests.  The topology is described inside a fixture (never at
+import: only one process may load the TPU library), and the persistent
+compile cache is off around the compiles, since a compile for a described
+chip can be written to it but never read back.
+"""
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.api import ExperimentSpec
+from repro.kernels.lock_arbiter import lock_arbiter
+from repro.kernels.multi_read import multi_read
+from repro.kernels.mvcc_version_select import mvcc_version_select
+from repro.workloads import make_workload
+
+SPEC = ExperimentSpec(protocol="mvcc", workload="ycsb")
+N_RECORDS = SPEC.n_nodes * SPEC.records_per_node
+YCSB = make_workload("ycsb", N_RECORDS)
+SLOTS = SPEC.n_nodes * SPEC.coroutines
+
+
+def _requests(workload: str) -> int:
+    return SLOTS * make_workload(workload, N_RECORDS).max_ops
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("TPU_LOG_DIR", "disabled")
+        try:
+            topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+        except Exception as e:
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+        was = jax.config.jax_enable_compilation_cache
+        jax.config.update("jax_enable_compilation_cache", False)
+        compilation_cache.reset_cache()
+        yield SingleDeviceSharding(topo.devices[0])
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
+
+
+def _compile(fn, one_chip, *shapes):
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip) for s, dt in shapes]
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("width", [2, YCSB.rw + 1], ids=["lock_words", "ycsb_data_ver"])
+def test_multi_read_compiles_for_v5e(one_chip, width):
+    m = _requests("ycsb")
+    assert m == 2400 and N_RECORDS == 262144
+    _compile(
+        lambda t, k: multi_read(t, k, interpret=False), one_chip,
+        ((N_RECORDS, width), jnp.int32), ((m,), jnp.int32),
+    )
+
+
+def test_mvcc_version_select_compiles_for_v5e(one_chip):
+    m, s = _requests("ycsb"), SPEC.mvcc_slots
+    _compile(
+        lambda *a: mvcc_version_select(*a, interpret=False), one_chip,
+        *[((m, s), jnp.int32)] * 2, *[((m,), jnp.int32)] * 4,
+    )
+
+
+@pytest.mark.parametrize("workload,m", [("ycsb", 2400), ("tpcc", 3600)])
+def test_lock_arbiter_compiles_for_v5e(one_chip, workload, m):
+    assert _requests(workload) == m
+    _compile(
+        lambda *a: lock_arbiter(*a, interpret=False), one_chip,
+        *[((1, m), jnp.int32)] * 3, ((1, m), jnp.bool_),
+    )
