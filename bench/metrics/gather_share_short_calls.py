@@ -1,0 +1,7 @@
+"""``gather_share`` in the short-call cell, where it moves
+``config_ticks_per_s.short_calls``."""
+from bench import scopes
+
+
+def read(run):
+    return scopes.gather_share(run)

@@ -41,8 +41,6 @@ YCSB_CODES = (0, 63, 0b010101, 0b101010)  # pure RPC, pure one-sided, two mixed
 KERNEL_PATH_PROTOCOLS = ("nowait", "mvcc", "sundial")
 NODE_CODE = 0b010101  # mixed coding: RPC and one-sided stages both cross the node mesh
 FOUR_CHIP_DEPTH = dict(ticks=100, warmup=20)  # depth cut; widths stay at paper scale
-COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
-CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
 
 
 def require(ok: bool, msg: str) -> None:
@@ -50,41 +48,16 @@ def require(ok: bool, msg: str) -> None:
         raise SystemExit(f"chip_smoke FAILED: {msg}")
 
 
-class CompileLog:
-    """Backend compiles (fresh or loaded from the persistent cache) as JAX
-    reports them; per-phase deltas are printed as set-up time."""
-
-    def __init__(self, monitoring):
-        self.count = 0
-        self.secs = 0.0
-        self.cache_hits = 0
-        monitoring.register_event_duration_secs_listener(self._duration)
-        monitoring.register_event_listener(self._event)
-
-    def _duration(self, event, secs, **_):
-        if event == COMPILE_EVENT:
-            self.count += 1
-            self.secs += secs
-
-    def _event(self, event, **_):
-        if event == CACHE_HIT_EVENT:
-            self.cache_hits += 1
-
-    def snapshot(self):
-        return self.count, self.secs, self.cache_hits
-
-
-def phase(name: str, log: CompileLog, fn):
+def phase(name: str, log, fn):
     print(f"# === phase {name}", flush=True)
-    c0, s0, h0 = log.snapshot()
+    c0, s0 = log.snapshot()
     t0 = time.time()
     fn()
     wall = time.time() - t0
-    c1, s1, h1 = log.snapshot()
+    c1, s1 = log.snapshot()
     print(
         f"# phase {name} (set-up, informational): {c1 - c0} compile(s), "
-        f"{h1 - h0} persistent-cache hit(s), compile {s1 - s0:.1f}s, "
-        f"wall {wall:.1f}s, run (wall - compile) {wall - (s1 - s0):.1f}s",
+        f"compile {s1 - s0:.1f}s, wall {wall:.1f}s, run (wall - compile) {wall - (s1 - s0):.1f}s",
         flush=True,
     )
 
@@ -121,6 +94,7 @@ def main() -> None:
     for p in (ROOT, os.path.join(ROOT, "src")):
         if p not in sys.path:
             sys.path.insert(0, p)
+    from bench.compile_log import CompileLog
     from benchmarks.common import configure_compile_cache
     from repro import api
     from repro.core import registry
@@ -207,9 +181,9 @@ def main() -> None:
         phase("(a) golden grid", log, golden)
         phase("(b) smallbank 2^6 x protocols", log, smallbank_grids)
         phase("(c) ycsb pallas vs jnp", log, ycsb_planes)
-    n, secs, hits = log.snapshot()
-    print(f"# total (set-up, informational): {n} compile(s), {hits} persistent-cache hit(s), "
-          f"compile {secs:.1f}s, wall {time.time() - t0:.1f}s", flush=True)
+    n, secs = log.snapshot()
+    print(f"# total (set-up, informational): {n} compile(s), compile {secs:.1f}s, "
+          f"wall {time.time() - t0:.1f}s", flush=True)
     print(json.dumps({
         "ok": True,
         "device": {"platform": dev.platform, "kind": dev.device_kind, "count": n_chips},

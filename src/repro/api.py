@@ -40,6 +40,7 @@ construction — and pinned by tests/test_api.py anyway.
 """
 from __future__ import annotations
 
+import functools
 import time
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional, Tuple, Union
@@ -223,6 +224,7 @@ def _resolve_devices(spec: ExperimentSpec, *, need: bool) -> Optional[Tuple[Any,
     return tuple(spec.devices)
 
 
+@functools.partial(jax.profiler.annotate_function, name="repro.plan")
 def plan(spec: ExperimentSpec) -> ExecutionPlan:
     """Resolve an :class:`ExperimentSpec` into an executable plan.
 
@@ -422,77 +424,99 @@ def execute(pl: ExecutionPlan) -> Results:
     ``engine.summarize`` plus ``wall_s`` / ``grid_size`` / ``n_buckets`` /
     ``bucket`` / ``n_devices`` / ``n_node_shards`` / ``protocol`` /
     ``workload`` / ``hybrid`` / resolved static axes), so existing consumers
-    and golden tests see identical dicts.
-    """
-    t0_all = time.time()
-    if pl.layout == NODE:
-        rows = [_execute_node(pl)]
-        return Results(rows=rows, plan=pl, wall_s=round(time.time() - t0_all, 2))
+    and golden tests see identical dicts.  ``wall_s`` is the host time of
+    the whole call, the interval of its ``repro.execute`` profiler span.
 
+    Profiler spans inside ``repro.execute``: ``.knobs`` (the knob arrays),
+    ``.dispatch`` (the jitted call, up to its return), ``.fetch`` (copying
+    the outputs to the host, which waits for the device) and ``.rows``.
+    """
+    if pl.layout == NODE:
+        row = _execute_node(pl)
+        return Results(rows=[row], plan=pl, wall_s=row["wall_s"])
+    return _execute_grid(pl)
+
+
+@functools.partial(jax.profiler.annotate_function, name="repro.execute")
+def _execute_grid(pl: ExecutionPlan) -> Results:
+    t0 = time.perf_counter()
     spec = pl.spec
     import jax.numpy as jnp
 
     rows: List[Optional[Dict]] = [None] * len(spec.configs)
     for pb in pl.buckets:
         b, gs = pb.bucket, pb.grid_spec
-        knobs = make_knobs(spec.workload, b.knob_configs)
-        if b.coroutines_active is not None:
-            knobs = knobs._replace(
-                coroutines_active=jnp.asarray(np.array(b.coroutines_active, np.int32))
-            )
-        if b.records_active is not None:
-            knobs = knobs._replace(
-                records_active=jnp.asarray(np.array(b.records_active, np.int32))
-            )
-        if b.ticks_active is not None:
-            knobs = knobs._replace(
-                ticks_active=jnp.asarray(np.array(b.ticks_active, np.int32))
-            )
-        t0 = time.time()
-        if pl.layout in (CONFIG, CONFIG_NODE):
-            out = _sweep._run_sharded(gs, knobs, list(pl.devices), pl.node_shards)
-        else:
-            if pl.devices is not None:  # honor an explicit single-device placement
+        with jax.profiler.TraceAnnotation("repro.execute.knobs"):
+            knobs = make_knobs(spec.workload, b.knob_configs)
+            if b.coroutines_active is not None:
+                knobs = knobs._replace(
+                    coroutines_active=jnp.asarray(np.array(b.coroutines_active, np.int32))
+                )
+            if b.records_active is not None:
+                knobs = knobs._replace(
+                    records_active=jnp.asarray(np.array(b.records_active, np.int32))
+                )
+            if b.ticks_active is not None:
+                knobs = knobs._replace(
+                    ticks_active=jnp.asarray(np.array(b.ticks_active, np.int32))
+                )
+            if pl.layout == DENSE and pl.devices is not None:
+                # honor an explicit single-device placement
                 knobs = jax.device_put(knobs, pl.devices[0])
-            out = {k: np.asarray(v) for k, v in _sweep._run_grid_jit(gs, knobs).items()}
-        wall = round(time.time() - t0, 2)
-        hy = np.asarray(knobs.hybrid)
-        for g, idx in enumerate(b.indices):
-            m = {k: v[g].tolist() for k, v in out.items()}
-            m["wall_s"] = wall
-            m["grid_size"] = len(spec.configs)
-            m["n_buckets"] = len(pl.buckets)
-            m["bucket"] = pb.index
-            m["n_devices"] = pl.n_devices
-            m["n_node_shards"] = pl.node_shards or 1
-            m["protocol"], m["workload"] = spec.protocol, spec.workload
-            m["hybrid"] = "".join(str(int(bit)) for bit in hy[g])
-            m["coroutines"] = (
-                b.coroutines if b.coroutines_active is None else b.coroutines_active[g]
-            )
-            m["records_per_node"] = (
-                b.records_per_node if b.records_active is None else b.records_active[g]
-            )
-            m["ticks"] = gs.ticks if b.ticks_active is None else b.ticks_active[g]
-            rows[idx] = m
-    return Results(rows=rows, plan=pl, wall_s=round(time.time() - t0_all, 2))  # type: ignore[arg-type]
+        with jax.profiler.TraceAnnotation("repro.execute.dispatch"):
+            if pl.layout in (CONFIG, CONFIG_NODE):
+                out = _sweep._run_sharded(gs, knobs, list(pl.devices), pl.node_shards)
+            else:
+                out = _sweep._run_grid_jit(gs, knobs)
+        with jax.profiler.TraceAnnotation("repro.execute.fetch"):
+            # a sharded grid is padded to the mesh: its pad rows go here
+            out = {k: np.asarray(v)[: len(b.indices)] for k, v in out.items()}
+        with jax.profiler.TraceAnnotation("repro.execute.rows"):
+            hy = np.asarray(knobs.hybrid)
+            for g, idx in enumerate(b.indices):
+                m = {k: v[g].tolist() for k, v in out.items()}
+                m["grid_size"] = len(spec.configs)
+                m["n_buckets"] = len(pl.buckets)
+                m["bucket"] = pb.index
+                m["n_devices"] = pl.n_devices
+                m["n_node_shards"] = pl.node_shards or 1
+                m["protocol"], m["workload"] = spec.protocol, spec.workload
+                m["hybrid"] = "".join(str(int(bit)) for bit in hy[g])
+                m["coroutines"] = (
+                    b.coroutines if b.coroutines_active is None else b.coroutines_active[g]
+                )
+                m["records_per_node"] = (
+                    b.records_per_node if b.records_active is None else b.records_active[g]
+                )
+                m["ticks"] = gs.ticks if b.ticks_active is None else b.ticks_active[g]
+                rows[idx] = m
+    wall = round(time.perf_counter() - t0, 4)
+    for m in rows:
+        m["wall_s"] = wall
+    return Results(rows=rows, plan=pl, wall_s=wall)  # type: ignore[arg-type]
 
 
+@functools.partial(jax.profiler.annotate_function, name="repro.execute")
 def _execute_node(pl: ExecutionPlan) -> Dict:
+    t0 = time.perf_counter()
     spec = pl.spec
     pb = pl.buckets[0]
-    knobs = make_knobs(spec.workload, pb.bucket.knob_configs)
-    knobs = jax.tree_util.tree_map(lambda x: x[0], knobs)
-    t0 = time.time()
-    runner = _sweep._node_runner(pb.grid_spec, list(pl.devices))
-    m = {k: np.asarray(v).tolist() for k, v in runner(knobs).items()}
-    m["wall_s"] = round(time.time() - t0, 2)
-    m["protocol"], m["workload"] = spec.protocol, spec.workload
-    m["n_node_shards"] = len(pl.devices)
-    hy = np.asarray(
-        normalize_hybrid(pb.bucket.knob_configs[0].get("hybrid", (RPC,) * N_HYBRID_STAGES))
-    )
-    m["hybrid"] = "".join(str(int(b)) for b in hy)
+    with jax.profiler.TraceAnnotation("repro.execute.knobs"):
+        knobs = make_knobs(spec.workload, pb.bucket.knob_configs)
+        knobs = jax.tree_util.tree_map(lambda x: x[0], knobs)
+    with jax.profiler.TraceAnnotation("repro.execute.dispatch"):
+        out = _sweep._node_runner(pb.grid_spec, list(pl.devices))(knobs)
+    with jax.profiler.TraceAnnotation("repro.execute.fetch"):
+        out = {k: np.asarray(v) for k, v in out.items()}
+    with jax.profiler.TraceAnnotation("repro.execute.rows"):
+        m = {k: v.tolist() for k, v in out.items()}
+        m["protocol"], m["workload"] = spec.protocol, spec.workload
+        m["n_node_shards"] = len(pl.devices)
+        hy = np.asarray(
+            normalize_hybrid(pb.bucket.knob_configs[0].get("hybrid", (RPC,) * N_HYBRID_STAGES))
+        )
+        m["hybrid"] = "".join(str(int(b)) for b in hy)
+    m["wall_s"] = round(time.perf_counter() - t0, 4)
     return m
 
 

@@ -307,58 +307,60 @@ def service_ops(ec: EngineConfig, cm: CostModel, st: Dict, op_mask, primitive_is
     Owned groups rank identically to the dense global sort (segment ranks
     are per-group), so the outcome is bitwise-equal.
     """
-    N, K = op_mask.shape
-    keys_f = st["keys"].reshape(-1)
-    active = op_mask.reshape(-1)
-    dest = jnp.clip(keys_f // ec.records_per_node, 0, ec.n_nodes - 1)
-    is_rpc_f = jnp.broadcast_to(primitive_is_rpc, op_mask.shape).reshape(-1)
+    with jax.named_scope("service"):
+        N, K = op_mask.shape
+        keys_f = st["keys"].reshape(-1)
+        active = op_mask.reshape(-1)
+        dest = jnp.clip(keys_f // ec.records_per_node, 0, ec.n_nodes - 1)
+        is_rpc_f = jnp.broadcast_to(primitive_is_rpc, op_mask.shape).reshape(-1)
 
-    # execution-phase co-routines starve their node's RPC handler (Fig. 9)
-    _, node, _ = logical_ids(ec)
-    exec_load = jnp.zeros((ec.n_nodes,), jnp.int32).at[node].add(
-        (st["exec_left"] > 0).astype(jnp.int32)
-    )
-    rpc_cap = jnp.maximum(cm.handler_cap - exec_load * jnp.maximum(1, ec.exec_ticks), 1)
-    nic_eff = jnp.asarray(cm.nic_eff_cap(), jnp.float32).astype(jnp.int32)
-    nic_cap = jnp.broadcast_to(nic_eff, (ec.n_nodes,))
+        # execution-phase co-routines starve their node's RPC handler (Fig. 9)
+        _, node, _ = logical_ids(ec)
+        exec_load = jnp.zeros((ec.n_nodes,), jnp.int32).at[node].add(
+            (st["exec_left"] > 0).astype(jnp.int32)
+        )
+        rpc_cap = jnp.maximum(cm.handler_cap - exec_load * jnp.maximum(1, ec.exec_ticks), 1)
+        nic_eff = jnp.asarray(cm.nic_eff_cap(), jnp.float32).astype(jnp.int32)
+        nic_cap = jnp.broadcast_to(nic_eff, (ec.n_nodes,))
 
-    # destination-side view: when sharded, a shard only ranks the requests
-    # targeting the nodes it owns (the rest sort to the inactive tail)
-    if ec.shard is None:
-        arrived = active
-    else:
-        nodes_per_shard = ec.n_nodes // ec.shard.n_shards
-        my_node = (dest // nodes_per_shard) == jax.lax.axis_index(ec.shard.axis)
-        arrived = active & my_node
+        # destination-side view: when sharded, a shard only ranks the requests
+        # targeting the nodes it owns (the rest sort to the inactive tail)
+        if ec.shard is None:
+            arrived = active
+        else:
+            nodes_per_shard = ec.n_nodes // ec.shard.n_shards
+            my_node = (dest // nodes_per_shard) == jax.lax.axis_index(ec.shard.axis)
+            arrived = active & my_node
 
-    # rank requests within (dest, plane) by hashed priority (arrival order);
-    # the LOGICAL op index keeps the draws padding-invariant
-    prio = hash_prio(op_index(ec, K).reshape(-1) + st["ts_lo"].repeat(K), salt)
-    group = dest * 2 + is_rpc_f.astype(jnp.int32)
-    sort_key = jnp.where(arrived, group * (2**20) + (prio & (2**20 - 1)), 2**30)
-    order = jnp.argsort(sort_key)
-    # rank within group via cumulative count in sorted order
-    g_sorted = group[order]
-    first = jnp.concatenate([jnp.ones(1, bool), g_sorted[1:] != g_sorted[:-1]])
-    idx_in_sorted = jnp.arange(N * K)
-    seg_start = jnp.where(first, idx_in_sorted, 0)
-    seg_start = jax.lax.associative_scan(jnp.maximum, seg_start)
-    rank_sorted = idx_in_sorted - seg_start
-    rank = jnp.zeros(N * K, jnp.int32).at[order].set(rank_sorted.astype(jnp.int32))
+        # rank requests within (dest, plane) by hashed priority (arrival order);
+        # the LOGICAL op index keeps the draws padding-invariant
+        prio = hash_prio(op_index(ec, K).reshape(-1) + st["ts_lo"].repeat(K), salt)
+        group = dest * 2 + is_rpc_f.astype(jnp.int32)
+        sort_key = jnp.where(arrived, group * (2**20) + (prio & (2**20 - 1)), 2**30)
+        order = jnp.argsort(sort_key)
+        # rank within group via cumulative count in sorted order
+        g_sorted = group[order]
+        first = jnp.concatenate([jnp.ones(1, bool), g_sorted[1:] != g_sorted[:-1]])
+        idx_in_sorted = jnp.arange(N * K)
+        seg_start = jnp.where(first, idx_in_sorted, 0)
+        seg_start = jax.lax.associative_scan(jnp.maximum, seg_start)
+        rank_sorted = idx_in_sorted - seg_start
+        rank = jnp.zeros(N * K, jnp.int32).at[order].set(rank_sorted.astype(jnp.int32))
 
-    cap = jnp.where(is_rpc_f, rpc_cap[dest], nic_cap[dest])
-    served = arrived & (rank < cap)
-    if ec.shard is not None:
-        # served-bit reply exchange back to the coordinators
-        served = jax.lax.psum(served.astype(jnp.int32), ec.shard.axis) > 0
+        cap = jnp.where(is_rpc_f, rpc_cap[dest], nic_cap[dest])
+        served = arrived & (rank < cap)
+        if ec.shard is not None:
+            # served-bit reply exchange back to the coordinators
+            with jax.named_scope("exchange"):
+                served = jax.lax.psum(served.astype(jnp.int32), ec.shard.axis) > 0
 
-    # same-plane per-dest load (for queue-delay accounting; (n_nodes, 2) is
-    # coordinator bookkeeping over the replicated request set — no exchange)
-    load = jnp.zeros((ec.n_nodes, 2), jnp.int32).at[dest, is_rpc_f.astype(jnp.int32)].add(
-        active.astype(jnp.int32)
-    )
-    op_load = load[dest, is_rpc_f.astype(jnp.int32)].astype(jnp.float32)
-    return served.reshape(N, K), op_load.reshape(N, K)
+        # same-plane per-dest load (for queue-delay accounting; (n_nodes, 2) is
+        # coordinator bookkeeping over the replicated request set — no exchange)
+        load = jnp.zeros((ec.n_nodes, 2), jnp.int32).at[dest, is_rpc_f.astype(jnp.int32)].add(
+            active.astype(jnp.int32)
+        )
+        op_load = load[dest, is_rpc_f.astype(jnp.int32)].astype(jnp.float32)
+        return served.reshape(N, K), op_load.reshape(N, K)
 
 
 def base_time(ec: EngineConfig, cm: CostModel, st: Dict, canon_stage) -> Dict:
@@ -424,9 +426,10 @@ def gather_rows(arr, keys):
 
 def read_rows(ec: EngineConfig, arr, keys):
     """Plane-routed row gather: one-sided READ round when node-sharded."""
-    if ec.shard is None:
-        return gather_rows(arr, keys)
-    return planes.node_read(ec.shard, arr, keys)
+    with jax.named_scope("gather"):
+        if ec.shard is None:
+            return gather_rows(arr, keys)
+        return planes.node_read(ec.shard, arr, keys)
 
 
 def read_rows_many(ec: EngineConfig, arrs: Sequence, keys) -> Tuple:
@@ -437,19 +440,21 @@ def read_rows_many(ec: EngineConfig, arrs: Sequence, keys) -> Tuple:
     (planes.node_read_batch) — dependent metadata reads of a round ride a
     single collective, mirroring §4.2's doorbell batching.
     """
-    if ec.shard is None:
-        if kops.is_pallas(ec.kernel_plane):
-            return kops.gather_many(arrs, keys, plane=ec.kernel_plane)
-        return tuple(gather_rows(a, keys) for a in arrs)
-    return planes.node_read_batch(ec.shard, arrs, keys, kernel_plane=ec.kernel_plane)
+    with jax.named_scope("gather"):
+        if ec.shard is None:
+            if kops.is_pallas(ec.kernel_plane):
+                return kops.gather_many(arrs, keys, plane=ec.kernel_plane)
+            return tuple(gather_rows(a, keys) for a in arrs)
+        return planes.node_read_batch(ec.shard, arrs, keys, kernel_plane=ec.kernel_plane)
 
 
 def read_rows2(ec: EngineConfig, arr, keys, sel):
     """(row, slot) gather from a (R, S, ...) store array (MVCC versions)."""
-    if ec.shard is None:
-        flat = arr[keys.reshape(-1), sel.reshape(-1)]
-        return flat.reshape(keys.shape + arr.shape[2:])
-    return planes.node_read2(ec.shard, arr, keys, sel)
+    with jax.named_scope("gather"):
+        if ec.shard is None:
+            flat = arr[keys.reshape(-1), sel.reshape(-1)]
+            return flat.reshape(keys.shape + arr.shape[2:])
+        return planes.node_read2(ec.shard, arr, keys, sel)
 
 
 def write_rows(ec: EngineConfig, arr, idx, vals, *, op: str = "set"):
@@ -480,14 +485,15 @@ def arb_winner(ec: EngineConfig, keys, prio_hi, prio_lo, active):
     won-bits combine in one exchange — bitwise the same winners (a key's
     contest happens entirely at its owner).
     """
-    if ec.shard is None:
-        return kops.cas_arbitrate(
-            keys, prio_hi, prio_lo, active, ec.n_records, plane=ec.kernel_plane
+    with jax.named_scope("arbitrate"):
+        if ec.shard is None:
+            return kops.cas_arbitrate(
+                keys, prio_hi, prio_lo, active, ec.n_records, plane=ec.kernel_plane
+            )
+        return planes.node_cas_winner(
+            ec.shard, ec.records_local, keys, prio_hi, prio_lo, active,
+            kernel_plane=ec.kernel_plane,
         )
-    return planes.node_cas_winner(
-        ec.shard, ec.records_local, keys, prio_hi, prio_lo, active,
-        kernel_plane=ec.kernel_plane,
-    )
 
 
 def scatter_ts_max(ec: EngineConfig, hi_arr, lo_arr, idx, ch, cl, active):
@@ -606,11 +612,12 @@ def run(
 
     # store layout is keyed by the registry FAMILY, so registered variants
     # (family="occ", ...) inherit the right metadata words
-    store = init_store(
-        protocol_family(ec.protocol), ec.records_local, wl.rw, wl.init_value,
-        n_versions=ec.mvcc_slots,
-    )
-    st = init_state(ec, wl)
+    with jax.named_scope("init"):
+        store = init_store(
+            protocol_family(ec.protocol), ec.records_local, wl.rw, wl.init_value,
+            n_versions=ec.mvcc_slots,
+        )
+        st = init_state(ec, wl)
 
     def tick(carry, t):
         st0, store0 = carry
@@ -635,7 +642,8 @@ def run(
         st["stage_us"] = jnp.zeros_like(st["stage_us"])
     (st, store), _ = jax.lax.scan(tick, (st, store), jnp.arange(warmup, warmup + n_ticks))
     n_eff = n_ticks if ticks_active is None else ticks_active
-    return st, store, summarize(ec, cm, st, n_eff)
+    with jax.named_scope("summarize"):
+        return st, store, summarize(ec, cm, st, n_eff)
 
 
 def run_sharded(

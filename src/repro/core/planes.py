@@ -50,6 +50,18 @@ def shard_map(f, *, mesh: Mesh, in_specs, out_specs):
     return jax.shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False)
 
 
+def _psum(x, axis: str):
+    """A round's reply exchange (one ``psum``), named ``exchange`` in traces."""
+    with jax.named_scope("exchange"):
+        return jax.lax.psum(x, axis)
+
+
+def _all_to_all(x, axis: str):
+    """One routed fabric exchange, named ``exchange`` in traces."""
+    with jax.named_scope("exchange"):
+        return jax.lax.all_to_all(x, axis, 0, 0, tiled=True)
+
+
 # ---------------------------------------------------------------------------
 # Engine transport: node-sharded store primitives (DESIGN.md §7)
 # ---------------------------------------------------------------------------
@@ -103,7 +115,7 @@ def node_read(shard: NodeShard, arr, keys):
     li, mine = _local_ix(shard, arr.shape[0], kf)
     vals = arr[li]
     vals = jnp.where(mine.reshape((-1,) + (1,) * (arr.ndim - 1)), vals, 0)
-    out = jax.lax.psum(vals, shard.axis)
+    out = _psum(vals, shard.axis)
     return out.reshape(keys.shape + arr.shape[1:])
 
 
@@ -119,14 +131,14 @@ def node_read_batch(shard: NodeShard, arrs: Sequence, keys, *, kernel_plane: str
     if kops.is_pallas(kernel_plane):
         table, widths = kops.pack_rows(arrs)
         v = kops.gather_rows_batch(table, li, plane=kernel_plane)
-        out = jax.lax.psum(jnp.where(mine[:, None], v, 0), shard.axis)
+        out = _psum(jnp.where(mine[:, None], v, 0), shard.axis)
     else:
         flat = []
         for a in arrs:
             v = a[li].reshape(kf.shape[0], -1)
             flat.append(jnp.where(mine[:, None], v, 0))
         widths = [f.shape[1] for f in flat]
-        out = jax.lax.psum(jnp.concatenate(flat, axis=1), shard.axis)
+        out = _psum(jnp.concatenate(flat, axis=1), shard.axis)
     return kops.unpack_rows(out, arrs, widths, keys.shape)
 
 
@@ -137,7 +149,7 @@ def node_read2(shard: NodeShard, arr, keys, sel):
     li, mine = _local_ix(shard, arr.shape[0], kf)
     vals = arr[li, sf]
     vals = jnp.where(mine.reshape((-1,) + (1,) * (arr.ndim - 2)), vals, 0)
-    out = jax.lax.psum(vals, shard.axis)
+    out = _psum(vals, shard.axis)
     return out.reshape(keys.shape + arr.shape[2:])
 
 
@@ -178,7 +190,7 @@ def node_cas_winner(shard: NodeShard, r_local: int, keys, prio_hi, prio_lo, acti
     """
     li, mine = _local_ix(shard, r_local, keys)
     win_l = kops.cas_arbitrate(li, prio_hi, prio_lo, active & mine, r_local, plane=kernel_plane)
-    return jax.lax.psum(win_l.astype(jnp.int32), shard.axis) > 0
+    return _psum(win_l.astype(jnp.int32), shard.axis) > 0
 
 
 def _route(requests, dest, n_nodes, cap):
@@ -228,11 +240,11 @@ def make_planes(mesh: Mesh, axis: str, records_per_node: int, rw: int, cap: int 
             dest = keys_l // records_per_node
             req = jnp.stack([keys_l % records_per_node, jnp.arange(m, dtype=jnp.int32)], 1)
             buf, _, slot = _route(req, dest, n_nodes, c)
-            inbox = jax.lax.all_to_all(buf, axis, 0, 0, tiled=True)  # (n*c, 2)
+            inbox = _all_to_all(buf, axis)  # (n*c, 2)
             inbox = inbox.reshape(n_nodes, c, 2)
             # RNIC DMA: raw gather, no handler logic
             vals = data_l[jnp.clip(inbox[..., 0], 0, data_l.shape[0] - 1)]
-            back = jax.lax.all_to_all(vals.reshape(n_nodes * c, rw), axis, 0, 0, tiled=True)
+            back = _all_to_all(vals.reshape(n_nodes * c, rw), axis)
             back = back.reshape(n_nodes, c, rw)
             # un-route: value for local request i sits at (dest[i], slot-in-dest);
             # dropped requests (slot >= c) must NOT alias slot c-1
@@ -256,8 +268,8 @@ def make_planes(mesh: Mesh, axis: str, records_per_node: int, rw: int, cap: int 
                 [keys_l % records_per_node, new_l, jnp.arange(m, dtype=jnp.int32)], 1
             )
             buf, valid, slot = _route(req, dest, n_nodes, c)
-            inbox = jax.lax.all_to_all(buf, axis, 0, 0, tiled=True).reshape(n_nodes, c, 3)
-            vwin = jax.lax.all_to_all(valid.astype(jnp.int32), axis, 0, 0, tiled=True)
+            inbox = _all_to_all(buf, axis).reshape(n_nodes, c, 3)
+            vwin = _all_to_all(valid.astype(jnp.int32), axis)
             v = vwin.reshape(n_nodes * c) > 0
             addr = inbox.reshape(-1, 3)[:, 0]
             newv = inbox.reshape(-1, 3)[:, 1]
@@ -269,9 +281,7 @@ def make_planes(mesh: Mesh, axis: str, records_per_node: int, rw: int, cap: int 
             lock_l = lock_l.at[jnp.where(ok, addr, lock_l.shape[0])].set(
                 jnp.where(ok, newv, 0), mode="drop"
             )
-            okb = jax.lax.all_to_all(
-                ok.reshape(n_nodes, c).astype(jnp.int32), axis, 0, 0, tiled=True
-            ).reshape(n_nodes, c)
+            okb = _all_to_all(ok.reshape(n_nodes, c).astype(jnp.int32), axis).reshape(n_nodes, c)
             # dropped requests never won (and must not alias slot c-1's result)
             keep = slot < c
             return lock_l, (okb[dest, jnp.minimum(slot, c - 1)] > 0) & keep
@@ -293,12 +303,10 @@ def make_planes(mesh: Mesh, axis: str, records_per_node: int, rw: int, cap: int 
             dest = keys_l // records_per_node
             req = jnp.stack([keys_l % records_per_node, jnp.arange(m, dtype=jnp.int32)], 1)
             buf, valid, slot = _route(req, dest, n_nodes, c)
-            inbox = jax.lax.all_to_all(buf, axis, 0, 0, tiled=True).reshape(n_nodes, c, 2)
-            vmask = jax.lax.all_to_all(valid.astype(jnp.int32), axis, 0, 0, tiled=True)
+            inbox = _all_to_all(buf, axis).reshape(n_nodes, c, 2)
+            vmask = _all_to_all(valid.astype(jnp.int32), axis)
             data_l, replies = handler(data_l, inbox[..., 0].reshape(-1), vmask.reshape(-1) > 0)
-            back = jax.lax.all_to_all(
-                replies.reshape(n_nodes * c, -1), axis, 0, 0, tiled=True
-            ).reshape(n_nodes, c, -1)
+            back = _all_to_all(replies.reshape(n_nodes * c, -1), axis).reshape(n_nodes, c, -1)
             # dropped requests get a zero reply, not another request's payload
             keep = slot < c
             return data_l, jnp.where(keep[:, None], back[dest, jnp.minimum(slot, c - 1)], 0)

@@ -19,6 +19,7 @@ lock/log/commit rounds) — a table entry, not a code fork.
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 from repro.core import engine as eng
@@ -75,25 +76,26 @@ def _version_pick(ec, wts: TS, ctts: TS, lock: TS = None):
     bitwise-equal across planes (the jnp path IS the original inline
     ``_best_version`` + R2 check, so pinned golden counters cannot move).
     """
-    if kops.is_pallas(ec.kernel_plane):
-        shp = wts.hi.shape[:-1]
-        S = wts.hi.shape[-1]
+    with jax.named_scope("version_select"):
+        if kops.is_pallas(ec.kernel_plane):
+            shp = wts.hi.shape[:-1]
+            S = wts.hi.shape[-1]
 
-        def flat(a):
-            return jnp.broadcast_to(a, shp).reshape(-1)
+            def flat(a):
+                return jnp.broadcast_to(a, shp).reshape(-1)
 
-        z = jnp.zeros(shp, jnp.int32)
-        lh, ll = (lock.hi, lock.lo) if lock is not None else (z, z)
-        found, slot, ok = kops.version_select(
-            wts.hi.reshape(-1, S), wts.lo.reshape(-1, S),
-            flat(ctts.hi), flat(ctts.lo), flat(lh), flat(ll),
-            plane=ec.kernel_plane,
-        )
-        r2 = ok.reshape(shp) if lock is not None else None
-        return found.reshape(shp), slot.reshape(shp), r2
-    found, slot = _best_version(wts, ctts)
-    r2 = None if lock is None else ts_is_zero(lock) | ts_lt(ctts, lock)
-    return found, slot, r2
+            z = jnp.zeros(shp, jnp.int32)
+            lh, ll = (lock.hi, lock.lo) if lock is not None else (z, z)
+            found, slot, ok = kops.version_select(
+                wts.hi.reshape(-1, S), wts.lo.reshape(-1, S),
+                flat(ctts.hi), flat(ctts.lo), flat(lh), flat(ll),
+                plane=ec.kernel_plane,
+            )
+            r2 = ok.reshape(shp) if lock is not None else None
+            return found.reshape(shp), slot.reshape(shp), r2
+        found, slot = _best_version(wts, ctts)
+        r2 = None if lock is None else ts_is_zero(lock) | ts_lt(ctts, lock)
+        return found, slot, r2
 
 
 def _max_wts(wts: TS) -> TS:

@@ -40,6 +40,7 @@ from repro.core.costmodel import (
     ST_COMMIT,
     ST_LOG,
     ST_VALIDATE,
+    STAGE_NAMES,
     CostModel,
     wire_cost,
 )
@@ -476,14 +477,16 @@ def make_tick(
 
     def tick(ec: eng.EngineConfig, cm: CostModel, wl, st: Dict, store: Dict, t):
         salt = t * salt_mult
-        st = begin_tick(ec, cm, wl, st, canon_map, start_stage, fresh_hook)
+        with jax.named_scope("begin_tick"):
+            st = begin_tick(ec, cm, wl, st, canon_map, start_stage, fresh_hook)
         for spec in specs:
-            if spec.kind == ROUND:
-                st, store = run_stage_round(ec, cm, wl, st, store, spec, salt + spec.salt_off)
-            elif spec.kind == LOG:
-                st = _log_round(ec, cm, wl, st, spec)
-            else:
-                st = _exec_stage(ec, wl, st, spec)
+            with jax.named_scope(f"stage_{STAGE_NAMES[spec.canon]}"):
+                if spec.kind == ROUND:
+                    st, store = run_stage_round(ec, cm, wl, st, store, spec, salt + spec.salt_off)
+                elif spec.kind == LOG:
+                    st = _log_round(ec, cm, wl, st, spec)
+                else:
+                    st = _exec_stage(ec, wl, st, spec)
         return st, store
 
     return tick

@@ -371,9 +371,9 @@ def _run_sharded(
     """Dispatch one bucket's grid with the config axis sharded over devices.
 
     Pads the grid to a multiple of the config-axis size by replicating the
-    last config (the pad rows are sliced off the output — they never reach
-    a caller).  With ``node_shards`` the mesh is 2-D ``config × node``:
-    each config's SIMULATION additionally runs node-sharded over the
+    last config; the outputs stay on the devices, pad rows included, and
+    the caller keeps the first rows, one per config.  With ``node_shards``
+    the mesh is 2-D ``config × node``: each config's SIMULATION additionally runs node-sharded over the
     ``node`` axis (every plane exchange inside the vmapped engine batches
     over the local configs), the same engine program
     :func:`~repro.core.engine.run_sharded` runs on a 1-D node mesh.
@@ -398,8 +398,7 @@ def _run_sharded(
         knobs = jax.tree_util.tree_map(
             lambda x: jnp.concatenate([x, jnp.repeat(x[-1:], pad, axis=0)], axis=0), knobs
         )
-    out = _grid_runner(spec, devices, node_shards)(knobs)
-    return {k: np.asarray(v)[:size] for k, v in out.items()}
+    return _grid_runner(spec, devices, node_shards)(knobs)
 
 
 def _legacy_grid(

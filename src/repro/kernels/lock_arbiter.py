@@ -72,5 +72,6 @@ def lock_arbiter(keys, prio_hi, prio_lo, active, *, block_m: int = 512, interpre
         out_specs=row_spec,
         out_shape=jax.ShapeDtypeStruct((G, 1, Mp), jnp.int32),
         interpret=interpret,
+        name="lock_arbiter",
     )(*rows, *cols)
     return active & (beaten[:, 0, :M] == 0)

@@ -66,5 +66,6 @@ def multi_read(table, keys, *, block_m: int = 256, block_r: int = 2048, interpre
         out_specs=pl.BlockSpec((block_m, A), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((Mp, A), jnp.int32),
         interpret=interpret,
+        name="multi_read",
     )(keys, table_t)
     return out[:M]

@@ -77,5 +77,6 @@ def mvcc_version_select(wts_hi, wts_lo, ctts_hi, ctts_lo, lock_hi, lock_lo,
         out_specs=[s1, s1, s1],
         out_shape=[jax.ShapeDtypeStruct((1, Mp), jnp.int32)] * 3,
         interpret=interpret,
+        name="mvcc_version_select",
     )(lanes2(wts_hi), lanes2(wts_lo), *map(lanes1, (ctts_hi, ctts_lo, lock_hi, lock_lo)))
     return found[0, :M] != 0, slot[0, :M], ok[0, :M] != 0
