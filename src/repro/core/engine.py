@@ -435,7 +435,7 @@ def read_rows(ec: EngineConfig, arr, keys):
 def read_rows_many(ec: EngineConfig, arrs: Sequence, keys) -> Tuple:
     """Gather several store arrays at the same keys.
 
-    Dense: independent gathers (jnp plane) or ONE packed multi-read kernel
+    Dense: independent gathers (jnp plane) or ONE multi-read kernel
     dispatch (Pallas planes).  Sharded: ONE doorbell-batched exchange
     (planes.node_read_batch) — dependent metadata reads of a round ride a
     single collective, mirroring §4.2's doorbell batching.

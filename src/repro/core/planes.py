@@ -124,22 +124,19 @@ def node_read_batch(shard: NodeShard, arrs: Sequence, keys, *, kernel_plane: str
     exchange.  The per-array replies are flattened along a feature axis,
     psum'd together, and split back — the collective analogue of posting
     dependent reads in a single doorbell (§4.2).  On a Pallas kernel plane
-    the owner's local gather is the fused multi-read kernel over the packed
-    table (the RNIC's DMA engine); the exchange structure is identical."""
+    the owner's local gather is the multi-read kernel, one DMA per key and
+    array (the RNIC's DMA engine); the exchange structure is identical."""
     kf = keys.reshape(-1)
     li, mine = _local_ix(shard, arrs[0].shape[0], kf)
-    if kops.is_pallas(kernel_plane):
-        table, widths = kops.pack_rows(arrs)
-        v = kops.gather_rows_batch(table, li, plane=kernel_plane)
-        out = _psum(jnp.where(mine[:, None], v, 0), shard.axis)
-    else:
-        flat = []
-        for a in arrs:
-            v = a[li].reshape(kf.shape[0], -1)
-            flat.append(jnp.where(mine[:, None], v, 0))
-        widths = [f.shape[1] for f in flat]
-        out = _psum(jnp.concatenate(flat, axis=1), shard.axis)
-    return kops.unpack_rows(out, arrs, widths, keys.shape)
+    vals = kops.gather_many(arrs, li, plane=kernel_plane)
+    flat = [jnp.where(mine[:, None], v.reshape(kf.shape[0], -1), 0) for v in vals]
+    out = _psum(jnp.concatenate(flat, axis=1), shard.axis)
+    outs, pos = [], 0
+    for a, f in zip(arrs, flat):
+        w = f.shape[1]
+        outs.append(out[:, pos : pos + w].reshape(keys.shape + a.shape[1:]))
+        pos += w
+    return tuple(outs)
 
 
 def node_read2(shard: NodeShard, arr, keys, sel):

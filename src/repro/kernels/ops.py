@@ -22,8 +22,8 @@ Parity contract (DESIGN.md §9, pinned by tests/test_kernel_parity.py and
 the kernel-parity CI job): for every protocol, integer counters under a
 Pallas plane are bitwise-equal to the jnp plane.  The kernels therefore
 implement *exactly* the reference semantics — lexicographic-min
-arbitration with no index tiebreak, and exact int32 one-hot gathers
-(never an f32 MXU matmul).
+arbitration with no index tiebreak, and row gathers made of DMA copies
+and an int32 lane select (never an f32 MXU matmul).
 
 The LM stack's flash-attention wrapper (`attention_op`) also lives here:
 same backend detection, cfg-level opt-in from models/lm.py.
@@ -124,40 +124,15 @@ def version_select(wts_hi, wts_lo, ctts_hi, ctts_lo, lock_hi, lock_lo, *, plane:
     )
 
 
-def gather_rows_batch(table, keys, *, plane: str = JNP):
-    """Packed-row gather: table (R, A) int32 at keys (M,) -> (M, A)."""
-    if not is_pallas(plane):
-        return table[keys]
-    return multi_read(table, keys, interpret=plane_interpret(plane))
-
-
-def pack_rows(arrs):
-    """Flatten several (R, ...) int32 arrays into one (R, A) packed table
-    (the doorbell payload) + the per-array flat widths."""
-    R = arrs[0].shape[0]
-    cols = [a.reshape(R, -1) for a in arrs]
-    table = cols[0] if len(cols) == 1 else jnp.concatenate(cols, axis=1)
-    return table, [c.shape[1] for c in cols]
-
-
-def unpack_rows(out, arrs, widths, keys_shape):
-    """Split a gathered (M, A) packed payload back into per-array results
-    shaped ``keys_shape + arr.shape[1:]``."""
-    outs, pos = [], 0
-    for a, w in zip(arrs, widths):
-        outs.append(out[:, pos : pos + w].reshape(keys_shape + a.shape[1:]))
-        pos += w
-    return tuple(outs)
-
-
 def gather_many(arrs, keys, *, plane: str = JNP):
-    """Doorbell-batched multi-array gather: ONE packed kernel dispatch for
-    several store arrays at the same keys (engine.read_rows_many's kernel
-    path).  Returns a tuple shaped like the per-array gathers."""
-    kf = keys.reshape(-1)
-    table, widths = pack_rows(arrs)
-    out = gather_rows_batch(table, kf, plane=plane)
-    return unpack_rows(out, arrs, widths, keys.shape)
+    """Doorbell-batched multi-array gather: several store arrays at the same
+    keys, ONE ``multi_read`` dispatch on a Pallas plane (engine.read_rows_many's
+    and planes.node_read_batch's read).  Returns a tuple of per-array
+    gathers, each shaped ``keys.shape + arr.shape[1:]``."""
+    if not is_pallas(plane):
+        kf = keys.reshape(-1)
+        return tuple(a[kf].reshape(keys.shape + a.shape[1:]) for a in arrs)
+    return multi_read(arrs, keys, interpret=plane_interpret(plane))
 
 
 # ---------------------------------------------------------------------------

@@ -48,6 +48,7 @@ def lock_arbiter_ref(keys, prio_hi, prio_lo, active):
 
 
 def multi_read_ref(table, keys):
-    """table (R, A), keys (M,) -> (M, A); negative (padding) keys gather 0."""
+    """table (R, ...), keys (...) -> keys.shape + table.shape[1:];
+    negative (padding) keys gather 0."""
     out = table[jnp.clip(keys, 0, table.shape[0] - 1)]
-    return jnp.where((keys >= 0)[:, None], out, 0)
+    return jnp.where((keys >= 0).reshape(keys.shape + (1,) * (table.ndim - 1)), out, 0)

@@ -166,18 +166,62 @@ def test_multi_read(R, A, M):
     ks = [jax.random.fold_in(KEY, R * A + M + i) for i in range(2)]
     table = jax.random.randint(ks[0], (R, A), -(2**28), 2**28, dtype=jnp.int32)
     keys = jax.random.randint(ks[1], (M,), 0, R, dtype=jnp.int32)
-    out = multi_read(table, keys, block_m=64, block_r=128, interpret=True)
+    (out,) = multi_read((table,), keys, interpret=True)
     assert bool((out == table[keys]).all())
+    assert bool((out == ref.multi_read_ref(table, keys)).all())
     # large int32 values survive exactly (no f32 rounding above 2^24)
     big = jnp.full((R, A), 2**30 - 7, jnp.int32)
-    out = multi_read(big, keys, interpret=True)
+    (out,) = multi_read((big,), keys, interpret=True)
     assert bool((out == 2**30 - 7).all())
 
 
 def test_multi_read_padding_keys_gather_zero():
     table = jnp.arange(12, dtype=jnp.int32).reshape(6, 2) + 1
     keys = jnp.asarray([0, -1, 5, -1], jnp.int32)
-    out = multi_read(table, keys, interpret=True)
+    (out,) = multi_read((table,), keys, interpret=True)
     exp = ref.multi_read_ref(table, keys)
     assert bool((out == exp).all())
     assert not np.asarray(out)[1].any() and not np.asarray(out)[3].any()
+
+
+@pytest.mark.parametrize("R,M", [(384, 1000), (300, 517), (130, 8)], ids=["R_lanes", "R_odd", "few_keys"])
+def test_multi_read_unpacked_widths(R, M):
+    """One call over a 1-D array and arrays of 1..9 words per record, each
+    returned in its own shape; R and M off every block size; the edge keys
+    (0, R - 1, padding) and the int32 extremes come back exactly."""
+    ks = jax.random.split(jax.random.fold_in(KEY, R + M), 12)
+    arrs = [jax.random.randint(ks[0], (R,), -(2**31) + 1, 2**31 - 1, jnp.int32)]
+    arrs += [
+        jax.random.randint(ks[w], (R, w), -(2**31) + 1, 2**31 - 1, jnp.int32) for w in range(1, 10)
+    ]
+    arrs[1] = arrs[1].at[R - 1].set(2**31 - 1).at[0].set(-(2**31) + 1)
+    arrs[0] = arrs[0].at[R - 1].set(-(2**31) + 1)
+    keys = jax.random.randint(ks[10], (M,), -2, R, dtype=jnp.int32)
+    keys = keys.at[0].set(R - 1).at[M - 1].set(0).at[M // 2].set(-1)
+    outs = multi_read(arrs, keys, interpret=True)
+    for a, o in zip(arrs, outs):
+        assert o.shape == keys.shape + a.shape[1:] and o.dtype == jnp.int32
+        assert bool((o == ref.multi_read_ref(a, keys)).all())
+        assert bool((o[keys >= 0] == a[keys[keys >= 0]]).all())
+    assert int(outs[0][0]) == -(2**31) + 1 and int(outs[1][0, 0]) == 2**31 - 1
+    # keys of any shape: the engine's (slots, ops) batches
+    k2 = keys[: (M // 4) * 4].reshape(-1, 4)
+    (o2,) = multi_read(arrs[3:4], k2, interpret=True)
+    assert bool((o2 == ref.multi_read_ref(arrs[3], k2)).all())
+
+
+@pytest.mark.parametrize("B", [3, 8], ids=["configs_3", "configs_8"])
+def test_multi_read_vmapped_over_configs(B):
+    """The sweep's config axis: each config reads its own tables at its own
+    keys, in ONE pallas_call (no loop of kernel calls around it)."""
+    R, M = 640, 300
+    ks = jax.random.split(jax.random.fold_in(KEY, B), 3)
+    lock = jax.random.randint(ks[0], (B, R), -(2**31) + 1, 2**31 - 1, jnp.int32)
+    data = jax.random.randint(ks[1], (B, R, 2), -(2**31) + 1, 2**31 - 1, jnp.int32)
+    keys = jax.random.randint(ks[2], (B, M), -1, R, dtype=jnp.int32).at[:, 0].set(R - 1)
+    read = jax.vmap(lambda l, d, k: multi_read((l, d), k, interpret=True))
+    jaxpr = str(jax.make_jaxpr(read)(lock, data, keys))
+    assert jaxpr.count("pallas_call[") == 1 and "while[" not in jaxpr
+    out_l, out_d = read(lock, data, keys)
+    assert bool((out_l == jax.vmap(ref.multi_read_ref)(lock, keys)).all())
+    assert bool((out_d == jax.vmap(ref.multi_read_ref)(data, keys)).all())
