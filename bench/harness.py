@@ -9,6 +9,14 @@ or None where it finds nothing to read.
 
 Each call of the window is what a user sends: ``repro.api.plan`` of an
 ``ExperimentSpec`` with ``kernel_plane="auto"``, then ``repro.api.execute``.
+A deployment that states ``node_shards`` lays the simulated nodes over that
+many chips, the cell's ``chips``: a call of one configuration then plans
+as the node layout (``api.NODE``).  Without it a call runs on one chip.
+
+The window keeps calls in flight, each on a thread of its own, so that the
+chip is fed while the host handles a call's rows or stands still: about
+:data:`AHEAD_S` seconds of calls behind the one it waits for.  Its rate is
+all the work of the calls sent over the time until the last has returned.
 """
 from __future__ import annotations
 
@@ -20,6 +28,8 @@ import shutil
 import sys
 import time
 import traceback
+from collections import deque
+from concurrent.futures import ThreadPoolExecutor
 from contextlib import nullcontext
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Tuple
@@ -30,6 +40,15 @@ BENCH = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(BENCH)
 TRACE_DIR = os.path.join(BENCH, ".trace")
 FLOAT_FIELDS = ("throughput_mtps", "avg_latency_us", "abort_rate", "avg_round_trips")
+AHEAD_S = 4.0  # seconds of calls the window sends ahead of the one it waits for
+MAX_IN_FLIGHT = 16  # calls in flight at most, each on a thread of its own
+
+
+def in_flight(call_s: float) -> int:
+    """Calls kept in flight when one call takes ``call_s`` seconds: the one
+    waited for and about :data:`AHEAD_S` of calls behind it, at least one."""
+    ahead = round(AHEAD_S / call_s) if call_s > 0 else MAX_IN_FLIGHT
+    return min(MAX_IN_FLIGHT, 1 + max(1, ahead))
 
 
 def read_json(path: str) -> dict:
@@ -60,6 +79,8 @@ def load_cell(name: str, bench: Optional[dict] = None) -> Cell:
         raise KeyError(f"no workload {name!r} in BENCHMARK.json; have {sorted(cells)}")
     w = cells[name]
     spec = read_json(os.path.join(BENCH, "cells", f"{name}.json"))
+    deployment = read_json(os.path.join(BENCH, "configs", f"{w['config']}.json"))
+    check_layout(deployment, w["chips"])
 
     def applies(m: dict) -> bool:
         return name in m.get("workloads", [name])
@@ -67,13 +88,26 @@ def load_cell(name: str, bench: Optional[dict] = None) -> Cell:
     return Cell(
         name=name,
         chips=w["chips"],
-        deployment=read_json(os.path.join(BENCH, "configs", f"{w['config']}.json")),
+        deployment=deployment,
         traffic=read_json(os.path.join(BENCH, "traffic", f"{w['traffic']}.json")),
         limits=spec["limits"],
         sample_rows=spec["sample_rows"],
         end_to_end=[m for m in bench["end_to_end"] if applies(m)],
         per_layer=[m for m in bench["per_layer"] if applies(m)],
     )
+
+
+def check_layout(deployment: dict, chips: int) -> None:
+    """A deployment's ``node_shards`` must be the cell's chips and divide its
+    nodes (each chip holds whole simulated nodes)."""
+    shards = deployment.get("node_shards")
+    if shards is None:
+        return
+    if shards != chips or deployment["n_nodes"] % shards:
+        raise ValueError(
+            f"deployment {deployment.get('name')!r}: node_shards={shards} must equal the "
+            f"cell's chips ({chips}) and divide n_nodes={deployment['n_nodes']}"
+        )
 
 
 def metric_reader(name: str):
@@ -105,6 +139,7 @@ class Run:
     warm_call_s: float = 0.0
     call_s: List[float] = field(default_factory=list)  # host seconds of each window call
     calls: List[Tuple[traffic_mod.Call, Optional[List[Dict]]]] = field(default_factory=list)
+    in_flight: int = 1  # window calls in flight after the first
     attempted: int = 0
     failed: int = 0
     traced_call: Optional[traffic_mod.Call] = None
@@ -116,9 +151,14 @@ class Run:
 
 
 def spec_for(api, deployment: dict, call: traffic_mod.Call, kernel_plane: str = "auto"):
+    """The ``ExperimentSpec`` of one call; ``node_shards`` (checked against the
+    cell's chips by :func:`check_layout`) places it over that many devices."""
     knobs = {"exec_ticks": deployment["exec_ticks"]}
     if "hot_prob" in deployment:
         knobs["hot_prob"] = deployment["hot_prob"]
+    layout = {}
+    if "node_shards" in deployment:
+        layout = {"node_shards": deployment["node_shards"], "devices": "auto"}
     return api.ExperimentSpec(
         protocol=call.protocol,
         workload=deployment["workload"],
@@ -130,6 +170,7 @@ def spec_for(api, deployment: dict, call: traffic_mod.Call, kernel_plane: str = 
         warmup=call.warmup,
         mvcc_slots=deployment["mvcc_slots"],
         kernel_plane=kernel_plane,
+        **layout,
     )
 
 
@@ -160,7 +201,7 @@ def run_cell(
     expect_plane: Optional[str] = "pallas",
     kernel_plane: str = "auto",
 ) -> Run:
-    """Warm up, run the window of back-to-back calls, trace one if asked.
+    """Warm up, run the window of calls, trace its first if asked.
 
     ``t_start`` is the process's start on ``time.perf_counter``; set-up is
     everything from there to the first timed call.  ``expect_plane`` is the
@@ -193,6 +234,20 @@ def run_cell(
             traceback.print_exc(file=sys.stderr)
             return None, None, False
 
+    def timed_call(call, span=nullcontext):
+        t0 = time.perf_counter()
+        return (*one_call(call, span), time.perf_counter() - t0)
+
+    def record(call, rows, plan_ms, ok, secs):
+        run.call_s.append(secs)
+        run.attempted += 1
+        run.calls.append((call, rows))
+        if ok:
+            run.plan_ms.append(plan_ms)
+            run.config_ticks += call.config_ticks
+        else:
+            run.failed += 1
+
     warm = next(stream)
     t0 = time.perf_counter()
     if not one_call(warm)[2]:
@@ -205,27 +260,32 @@ def run_cell(
         shutil.rmtree(TRACE_DIR, ignore_errors=True)
     t_win = time.perf_counter()
     run.setup_s = t_win - t_start
-    while True:
-        call = next(stream)
-        t0 = time.perf_counter()
-        if trace and run.traced_call is None:
-            jax.profiler.start_trace(TRACE_DIR)
-            with jax.profiler.TraceAnnotation("bench.call"):
-                rows, plan_ms, ok = one_call(call, jax.profiler.TraceAnnotation)
-            jax.profiler.stop_trace()
-            run.traced_call = call
-        else:
-            rows, plan_ms, ok = one_call(call)
-        run.call_s.append(time.perf_counter() - t0)
-        run.attempted += 1
-        run.calls.append((call, rows))
-        if ok:
-            run.plan_ms.append(plan_ms)
-            run.config_ticks += call.config_ticks
-        else:
-            run.failed += 1
-        if time.perf_counter() - t_win >= seconds:
-            break
+    # The window's first call runs alone: it is the traced call, and its
+    # time sets how many calls the rest of the window keeps in flight.
+    first = next(stream)
+    if trace:
+        jax.profiler.start_trace(TRACE_DIR)
+        with jax.profiler.TraceAnnotation("bench.call"):
+            out = timed_call(first, jax.profiler.TraceAnnotation)
+        jax.profiler.stop_trace()
+        run.traced_call = first
+    else:
+        out = timed_call(first)
+    record(first, *out)
+    run.in_flight = in_flight(run.call_s[0])
+    # The rest: each call is sent while those before it still run, so the
+    # device waits on no host work between calls.  When time is up nothing
+    # more is sent; the window ends when every call sent has returned.
+    with ThreadPoolExecutor(max_workers=run.in_flight) as pool:
+        sent = deque()
+        while True:
+            while len(sent) < run.in_flight and time.perf_counter() - t_win < seconds:
+                call = next(stream)
+                sent.append((call, pool.submit(timed_call, call)))
+            if not sent:
+                break
+            call, fut = sent.popleft()
+            record(call, *fut.result())
     run.window_s = time.perf_counter() - t_win
     c1, _ = log.snapshot()
     run.window_compiles = c1 - c0

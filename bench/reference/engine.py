@@ -1,10 +1,12 @@
-"""Reference engine: one dense, unpadded, single-device simulator run.
+"""Reference engine: one dense, unpadded simulator run.
 
 The same bulk-synchronous semantics as the simulator under test (one tick =
 one network round; RPC requests queue on the destination handler CPU,
 one-sided verbs on its RNIC), written out with plain ``jax.numpy`` gathers
-and scatters: no kernel plane, no node sharding, no shape-bucket padding,
-no history recording.  It imports nothing of the program.
+and scatters: no kernel plane, no node-sharded transport, no shape-bucket
+padding, no history recording.  It imports nothing of the program.  The
+store may be laid out over several devices (``EngineConfig.place``); XLA then
+partitions the same gathers and scatters, and the semantics do not change.
 
 ``EngineConfig.fdt`` is the dtype of the latency accumulators (per-txn
 latency, latency and round-trip sums, per-stage time).  float32 is the
@@ -58,25 +60,10 @@ class WireCost:
         return b * (n_backups if self.replicated else 1)
 
 
-_LOG_WIRE = WireCost(base=8.0, words=1.0, replicated=True)
-_RELEASE_WIRE = WireCost(base=8.0)
-
-WIRE_COSTS = {
-    "nowait": {
-        ST_LOCK: WireCost(base=16.0, words=1.0, n_verbs=2),
-        ST_LOG: _LOG_WIRE,
-        ST_COMMIT: WireCost(base=12.0, words=1.0, n_verbs=2),
-        ST_RELEASE: _RELEASE_WIRE,
-    },
-    "mvcc": {
-        ST_FETCH: WireCost(base=48.0, words=8.0, n_verbs=2),
-        ST_LOCK: WireCost(base=24.0, words=1.0, n_verbs=2),
-        ST_VALIDATE: WireCost(base=16.0),
-        ST_LOG: _LOG_WIRE,
-        ST_COMMIT: WireCost(base=16.0, words=1.0, n_verbs=2),
-        ST_RELEASE: _RELEASE_WIRE,
-    },
-}
+# The wire costs shared by the protocols; each protocol's plug-in holds its
+# own table of a WireCost per canonical stage.
+LOG_WIRE = WireCost(base=8.0, words=1.0, replicated=True)
+RELEASE_WIRE = WireCost(base=8.0)
 
 
 def queue_delay_us(cm: CostModel, is_rpc, dest_load):
@@ -121,14 +108,15 @@ def hash_prio(x, salt):
     return (x & jnp.uint32(0x7FFFFFFF)).astype(jnp.int32)
 
 
-def cas_winner(keys, prio_hi, prio_lo, active, n_records):
-    """Per-key lexicographic-min (prio_hi, prio_lo) among active requests."""
+def cas_winner(keys, prio_hi, prio_lo, active, n_records, place):
+    """Per-key lexicographic-min (prio_hi, prio_lo) among active requests;
+    ``place`` lays out the per-record scratch as the store is laid out."""
     big = jnp.int32(2**31 - 1)
-    best_hi = jnp.full((n_records,), big, jnp.int32).at[keys].min(
+    best_hi = place(jnp.full((n_records,), big, jnp.int32)).at[keys].min(
         jnp.where(active, prio_hi, big), mode="drop"
     )
     hi_ok = active & (prio_hi == best_hi[keys])
-    best_lo = jnp.full((n_records,), big, jnp.int32).at[keys].min(
+    best_lo = place(jnp.full((n_records,), big, jnp.int32)).at[keys].min(
         jnp.where(hi_ok, prio_lo, big), mode="drop"
     )
     return hi_ok & (prio_lo == best_lo[keys])
@@ -151,7 +139,6 @@ class Workload(NamedTuple):
 
 @dataclass(frozen=True)
 class EngineConfig:
-    protocol: str
     n_nodes: int
     coroutines: int
     records_per_node: int
@@ -163,6 +150,9 @@ class EngineConfig:
     mvcc_slots: int = 4
     doorbell: bool = True
     fdt: Any = jnp.float32
+    # lays out an array whose leading axis is the record: the identity on one
+    # device, a sharding constraint over a device mesh
+    place: Callable = lambda a: a
 
     @property
     def n_slots(self) -> int:
@@ -173,21 +163,9 @@ class EngineConfig:
         return self.n_nodes * self.records_per_node
 
 
-def init_store(protocol: str, n_records: int, rw: int, init_value: int, n_versions: int) -> Dict:
-    def z(*s):
-        return jnp.zeros(s, jnp.int32)
-
-    store = {"lock_hi": z(n_records), "lock_lo": z(n_records), "ver": z(n_records)}
-    if protocol == "mvcc":
-        store["wts_hi"] = z(n_records, n_versions)
-        store["wts_lo"] = z(n_records, n_versions).at[:, 0].set(1)
-        store["rts_hi"] = z(n_records)
-        store["rts_lo"] = z(n_records)
-        store["vdata"] = jnp.full((n_records, n_versions, rw), init_value, jnp.int32)
-        store["vver"] = z(n_records, n_versions)
-    else:
-        store["data"] = jnp.full((n_records, rw), init_value, jnp.int32)
-    return store
+def lock_store(n_records: int) -> Dict:
+    """The words every protocol's store has: the lock timestamp and a version."""
+    return {k: jnp.zeros((n_records,), jnp.int32) for k in ("lock_hi", "lock_lo", "ver")}
 
 
 def init_state(ec: EngineConfig) -> Dict:
@@ -342,11 +320,11 @@ def scatter2(arr, idx, sel, vals):
 def scatter_ts_max(ec: EngineConfig, hi_arr, lo_arr, idx, ch, cl, active):
     """Lexicographic scatter-max of (ch, cl) into a stored timestamp pair."""
     r = ec.n_records
-    cand_hi = jnp.full((r,), -(2**31), jnp.int32).at[idx].max(
+    cand_hi = ec.place(jnp.full((r,), -(2**31), jnp.int32)).at[idx].max(
         jnp.where(active, ch, -(2**31)), mode="drop"
     )
     at_max = active & (ch == cand_hi[jnp.clip(idx, 0, r - 1)])
-    cand_lo = jnp.full((r,), -(2**31), jnp.int32).at[idx].max(
+    cand_lo = ec.place(jnp.full((r,), -(2**31), jnp.int32)).at[idx].max(
         jnp.where(at_max, cl, -(2**31)), mode="drop"
     )
     upd = (hi_arr < cand_hi) | ((hi_arr == cand_hi) & (lo_arr < cand_lo))
@@ -359,7 +337,7 @@ def try_lock(ec: EngineConfig, store, st, op_mask, prio_hi, prio_lo):
     N, K = op_mask.shape
     keys_f = st["keys"].reshape(-1)
     win = cas_winner(keys_f, prio_hi.reshape(-1), prio_lo.reshape(-1), op_mask.reshape(-1),
-                     ec.n_records)
+                     ec.n_records, ec.place)
     lock = TS(gather(store["lock_hi"], st["keys"]), gather(store["lock_lo"], st["keys"]))
     mine = ts_eq(lock, TS(st["ts_hi"][:, None], st["ts_lo"][:, None]))
     won = win.reshape(N, K) & (ts_is_zero(lock) | mine) & op_mask
@@ -399,14 +377,27 @@ def finish_abort(st: Dict, mask) -> Dict:
 # ---------------------------------------------------------------------------
 
 
-def run(tick, ec: EngineConfig, cm: CostModel, wl: Workload, n_ticks: int, warmup: int) -> Dict:
-    """Warm up, reset the counters, run ``n_ticks`` measured ticks, summarize."""
-    store = init_store(ec.protocol, ec.n_records, ec.rw, wl.init_value, ec.mvcc_slots)
+class Protocol(NamedTuple):
+    """A protocol as a reference plug-in gives it (``protocol_<name>.py``)."""
+
+    tick: Callable  # tick(ec, cm, wl, st, store, t) -> (st, store)
+    init_store: Callable  # init_store(n_records, rw, init_value, n_versions) -> {name: (R, ...)}
+
+
+def run(proto: Protocol, ec: EngineConfig, cm: CostModel, wl: Workload, n_ticks: int,
+        warmup: int) -> Dict:
+    """Warm up, reset the counters, run ``n_ticks`` measured ticks, summarize.
+    Every tick's store is laid out by ``ec.place``."""
+
+    def place(store):
+        return {k: ec.place(v) for k, v in store.items()}
+
+    store = place(proto.init_store(ec.n_records, ec.rw, wl.init_value, ec.mvcc_slots))
     st = init_state(ec)
 
     def body(carry, t):
-        st, store = tick(ec, cm, wl, *carry, t)
-        return (st, store), None
+        st, store = proto.tick(ec, cm, wl, *carry, t)
+        return (st, place(store)), None
 
     if warmup:
         (st, store), _ = jax.lax.scan(body, (st, store), jnp.arange(warmup))

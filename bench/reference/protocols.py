@@ -1,7 +1,8 @@
-"""Reference stage machines: NOWAIT and MVCC on the reference engine.
+"""Reference stage machines: what the protocols on the reference engine share.
 
-A protocol is a table of stages processed in reverse pipeline order each
-tick, so a transaction advances at most one network stage per tick.  Each
+A protocol (``protocol_<name>.py``) is a table of stages processed in
+reverse pipeline order each tick, so a transaction advances at most one
+network stage per tick.  Each
 serviced round runs: want-mask -> capacity service -> the stage's effect ->
 latency accounting -> served bookkeeping -> stage transition.  Stages are
 never merged across doorbells (the default of the system under test).
@@ -15,14 +16,10 @@ import jax
 import jax.numpy as jnp
 
 from bench.reference import engine as eng
-from bench.reference.engine import (
-    RPC, ST_COMMIT, ST_EXEC, ST_FETCH, ST_LOCK, ST_LOG, ST_RELEASE, ST_VALIDATE, TS,
-    ts_eq, ts_is_zero, ts_lt,
-)
+from bench.reference.engine import RPC
 
 FRESH = -1
 ROUND, LOG, EXEC = "round", "log", "exec"
-_MIN = jnp.int32(-(2**31))
 
 
 class StageOut(NamedTuple):
@@ -117,14 +114,14 @@ def abort_to_retry(st: Dict, fail, spec: Stage) -> Dict:
 # ---- one serviced round of a stage ----------------------------------------
 
 
-def stage_round(ec, cm, wl, st, store, spec: Stage, salt):
+def stage_round(ec, cm, wl, st, store, spec: Stage, salt, wire):
     prim = ec.hybrid[spec.canon]
     in_s = st["stage"] == spec.stage
     want = in_s[:, None] & spec.ops(st)
     served, load = eng.service_ops(ec, cm, st, want, prim == RPC, salt)
     out = spec.effect(ec, cm, wl, st, store, in_s, served, salt)
     st, store = dict(out.st), out.store
-    wc = eng.WIRE_COSTS[ec.protocol][spec.canon]
+    wc = wire[spec.canon]
     st = eng.account_round(
         ec, cm, st, spec.canon, served, load, prim, wc.bytes_for(wl.rw, cm.n_backups), wc.n_verbs
     )
@@ -173,13 +170,13 @@ def stage_round(ec, cm, wl, st, store, spec: Stage, salt):
     return st, store
 
 
-def log_round(ec, cm, wl, st, spec: Stage):
+def log_round(ec, cm, wl, st, spec: Stage, wire):
     """Fire-and-forget log to the backups: no service arbitration."""
     prim = ec.hybrid[spec.canon]
     in_g = st["stage"] == spec.stage
     ops = in_g[:, None] & st["is_w"] & st["valid"]
     load = jnp.full(ops.shape, float(cm.n_backups), jnp.float32)
-    wc = eng.WIRE_COSTS[ec.protocol][spec.canon]
+    wc = wire[spec.canon]
     st = eng.account_round(
         ec, cm, st, spec.canon, ops, load, prim, wc.bytes_for(wl.rw, cm.n_backups), wc.n_verbs
     )
@@ -201,7 +198,9 @@ def exec_stage(ec, wl, st, spec: Stage):
     return st
 
 
-def make_tick(specs, start_stage: int, salt_mult: int):
+def make_tick(specs, start_stage: int, salt_mult: int, wire: Dict[int, eng.WireCost]):
+    """One tick of a stage table; ``wire`` is the protocol's WireCost per
+    canonical stage of its network rounds."""
     canon_map = {s.stage: s.canon for s in specs}
 
     def tick(ec, cm, wl, st, store, t):
@@ -215,212 +214,11 @@ def make_tick(specs, start_stage: int, salt_mult: int):
         st = eng.base_time(ec, cm, st, canon)
         for spec in specs:
             if spec.kind == ROUND:
-                st, store = stage_round(ec, cm, wl, st, store, spec, salt + spec.salt_off)
+                st, store = stage_round(ec, cm, wl, st, store, spec, salt + spec.salt_off, wire)
             elif spec.kind == LOG:
-                st = log_round(ec, cm, wl, st, spec)
+                st = log_round(ec, cm, wl, st, spec, wire)
             else:
                 st = exec_stage(ec, wl, st, spec)
         return st, store
 
     return tick
-
-
-# ---- NOWAIT ---------------------------------------------------------------
-
-N_LOCK, N_EXEC, N_LOG, N_COMMIT, N_ABREL = range(5)
-
-
-def _nowait_lock(ec, cm, wl, st, store, in_l, served, salt):
-    """Arbitrated CAS + fetch under the lock; any lost CAS aborts the txn.
-    RPC lock requests park on the owner (``served`` accumulates); one-sided
-    requests re-post every tick."""
-    is_rpc = jnp.asarray(ec.hybrid[ST_LOCK] == RPC)
-    st = dict(st)
-    pend = in_l[:, None] & st["valid"] & ~st["locked"]
-    acc = served & is_rpc
-    contenders = jnp.where(is_rpc, pend & (st["served"] | acc), served)
-    K = contenders.shape[1]
-    sid, _ = eng.slot_ids(ec)
-    base = sid[:, None] * K + jnp.arange(K, dtype=jnp.int32)[None, :]
-    prio_hi = eng.hash_prio(base + st["ts_lo"][:, None], salt + 1)
-    won, store = eng.try_lock(ec, store, st, contenders, prio_hi, base)
-    st["locked"] = st["locked"] | won
-    got = eng.gather(store["data"], st["keys"])
-    ver = eng.gather(store["ver"], st["keys"])
-    st["rvals"] = jnp.where(won[:, :, None], got, st["rvals"])
-    st["ver_seen"] = jnp.where(won, ver, st["ver_seen"])
-    abort_now = in_l & (contenders & ~won).any(1)
-    return StageOut(st, store, fail=abort_now, served_acc=acc,
-                    outstanding=st["valid"] & ~st["locked"])
-
-
-NOWAIT = (
-    Stage(N_COMMIT, ST_COMMIT, ops=ops_valid, effect=writeback_commit_effect, done="commit",
-          salt_off=1),
-    Stage(N_ABREL, ST_RELEASE, ops=ops_locked, effect=release_effect, done="abort",
-          next_stage=N_LOCK, salt_off=2),
-    Stage(N_LOG, ST_LOG, kind=LOG, next_stage=N_COMMIT),
-    Stage(N_EXEC, ST_EXEC, kind=EXEC, next_stage=N_LOG),
-    Stage(N_LOCK, ST_LOCK, ops=ops_lock_pending(False), effect=_nowait_lock, next_stage=N_EXEC,
-          start_exec=True, retry_stage=N_LOCK, abrel_stage=N_ABREL, salt_off=3),
-)
-
-# ---- MVCC -----------------------------------------------------------------
-
-M_READ, M_RTS, M_LOCKW, M_EXEC, M_LOG, M_COMMIT, M_ABREL = range(7)
-
-
-def _vts(store, keys) -> TS:
-    return TS(eng.gather(store["wts_hi"], keys), eng.gather(store["wts_lo"], keys))
-
-
-def _lex_lt(ah, al, bh, bl):
-    return (ah < bh) | ((ah == bh) & (al < bl))
-
-
-def _best_version(wts: TS, ctts: TS):
-    """Cond R1: the slot with the largest committed wts strictly below ctts."""
-    cand = _lex_lt(wts.hi, wts.lo, ctts.hi[..., None], ctts.lo[..., None]) & ~ts_is_zero(wts)
-    best_h = jnp.where(cand, wts.hi, _MIN).max(-1, keepdims=True)
-    is_h = cand & (wts.hi == best_h)
-    best_l = jnp.where(is_h, jnp.where(cand, wts.lo, _MIN), _MIN).max(-1, keepdims=True)
-    winner = is_h & (wts.lo == best_l)
-    return cand.any(-1), jnp.argmax(winner, axis=-1).astype(jnp.int32)
-
-
-def _pick(wts: TS, ctts: TS, lock: Optional[TS] = None):
-    found, slot = _best_version(wts, ctts)
-    r2 = None if lock is None else ts_is_zero(lock) | ts_lt(ctts, lock)
-    return found, slot, r2
-
-
-def _max_wts(wts: TS) -> TS:
-    bh = wts.hi.max(-1, keepdims=True)
-    bl = jnp.where(wts.hi == bh, wts.lo, _MIN).max(-1)
-    return TS(bh[..., 0], bl)
-
-
-def _oldest_slot(wts: TS):
-    bh = wts.hi.min(-1, keepdims=True)
-    is_h = wts.hi == bh
-    bl = jnp.where(is_h, wts.lo, jnp.int32(2**31 - 1)).min(-1, keepdims=True)
-    return jnp.argmax(is_h & (wts.lo == bl), axis=-1).astype(jnp.int32)
-
-
-def _check_w1(store, st, ops):
-    """Cond W1: ctts above every version's wts and above rts."""
-    mx = _max_wts(_vts(store, st["keys"]))
-    rts = TS(eng.gather(store["rts_hi"], st["keys"]), eng.gather(store["rts_lo"], st["keys"]))
-    me = TS(st["ts_hi"][:, None], st["ts_lo"][:, None])
-    ok = _lex_lt(mx.hi, mx.lo, me.hi, me.lo) & _lex_lt(rts.hi, rts.lo, me.hi, me.lo)
-    return ok | ~ops
-
-
-def _mvcc_commit(ec, cm, wl, st, store, in_c, served, salt):
-    """Overwrite the oldest version slot and its record, then unlock."""
-    st = dict(st)
-    oldest = _oldest_slot(_vts(store, st["keys"]))
-    ver = eng.gather(store["ver"], st["keys"])
-    K = st["keys"].shape[1]
-    keys_f = st["keys"].reshape(-1)
-    idx_k = jnp.where(served.reshape(-1), keys_f, ec.n_records)
-    idx_s = oldest.reshape(-1)
-    store = dict(store)
-    store["wts_hi"] = eng.scatter2(store["wts_hi"], idx_k, idx_s, jnp.repeat(st["ts_hi"], K))
-    store["wts_lo"] = eng.scatter2(store["wts_lo"], idx_k, idx_s, jnp.repeat(st["ts_lo"], K))
-    store["vdata"] = eng.scatter2(store["vdata"], idx_k, idx_s, st["wvals"].reshape(-1, wl.rw))
-    store["vver"] = eng.scatter2(store["vver"], idx_k, idx_s, (ver + 1).reshape(-1))
-    store["ver"] = eng.scatter(store["ver"], idx_k, 1, op="add")
-    idx_r = jnp.where((served & st["locked"]).reshape(-1), keys_f, ec.n_records)
-    store["lock_hi"] = eng.scatter(store["lock_hi"], idx_r, 0)
-    store["lock_lo"] = eng.scatter(store["lock_lo"], idx_r, 0)
-    st["locked"] = st["locked"] & ~served
-    return StageOut(st, store)
-
-
-def _mvcc_lock(ec, cm, wl, st, store, in_l, served, salt):
-    """CAS tts + READ, then re-check W1 under the lock (double-read)."""
-    st = dict(st)
-    won, store = eng.try_lock(
-        ec, store, st, served,
-        jnp.broadcast_to(st["ts_hi"][:, None], served.shape),
-        jnp.broadcast_to(st["ts_lo"][:, None], served.shape),
-    )
-    st["locked"] = st["locked"] | won
-    found, slot, _ = _pick(_vts(store, st["keys"]), TS(st["ts_hi"][:, None], st["ts_lo"][:, None]))
-    st["rvals"] = jnp.where(won[:, :, None], eng.gather2(store["vdata"], st["keys"], slot), st["rvals"])
-    st["ver_seen"] = jnp.where(won, eng.gather2(store["vver"], st["keys"], slot), st["ver_seen"])
-    w1_ok = _check_w1(store, st, won)
-    fail = in_l & ((served & ~won).any(1) | (won & ~w1_ok).any(1) | (won & ~found).any(1))
-    return StageOut(st, store, fail=fail, served_acc=jnp.zeros_like(served),
-                    outstanding=st["valid"] & st["is_w"] & ~st["locked"])
-
-
-def _mvcc_rts(ec, cm, wl, st, store, in_t, served, salt):
-    """rts CAS-max, valid only while the read version is still the newest
-    below ctts and Cond R2 still holds."""
-    st = dict(st)
-    wts_now = _vts(store, st["keys"])
-    ctts = TS(st["ts_hi"][:, None], st["ts_lo"][:, None])
-    lock_now = TS(eng.gather(store["lock_hi"], st["keys"]), eng.gather(store["lock_lo"], st["keys"]))
-    found_now, slot_now, r2_now = _pick(wts_now, ctts, lock_now)
-    best_now = TS(
-        jnp.take_along_axis(wts_now.hi, slot_now[..., None], axis=-1)[..., 0],
-        jnp.take_along_axis(wts_now.lo, slot_now[..., None], axis=-1)[..., 0],
-    )
-    still_ok = found_now & ts_eq(best_now, TS(st["wts_seen_hi"], st["wts_seen_lo"])) & r2_now
-    fail = in_t & (served & ~still_ok).any(1)
-    served = served & still_ok
-    K = st["keys"].shape[1]
-    sf = served.reshape(-1)
-    idx = jnp.where(sf, st["keys"].reshape(-1), ec.n_records)
-    store = dict(store)
-    store["rts_hi"], store["rts_lo"] = eng.scatter_ts_max(
-        ec, store["rts_hi"], store["rts_lo"], idx,
-        jnp.repeat(st["ts_hi"], K), jnp.repeat(st["ts_lo"], K), sf,
-    )
-    return StageOut(st, store, fail=fail, served_acc=served)
-
-
-def _mvcc_read(ec, cm, wl, st, store, in_f, served, salt):
-    """Atomic double-read, version pick, clock drift adjustment, W1 precheck."""
-    st = dict(st)
-    wts = _vts(store, st["keys"])
-    lock = TS(eng.gather(store["lock_hi"], st["keys"]), eng.gather(store["lock_lo"], st["keys"]))
-    rts_obs = eng.gather(store["rts_hi"], st["keys"])
-    found, slot, r2 = _pick(wts, TS(st["ts_hi"][:, None], st["ts_lo"][:, None]), lock)
-    rs_served = served & st["valid"] & ~st["is_w"]
-    st["rvals"] = jnp.where(rs_served[:, :, None], eng.gather2(store["vdata"], st["keys"], slot),
-                            st["rvals"])
-    st["ver_seen"] = jnp.where(rs_served, eng.gather2(store["vver"], st["keys"], slot), st["ver_seen"])
-    best_hi = jnp.take_along_axis(wts.hi, slot[..., None], axis=-1)[..., 0]
-    best_lo = jnp.take_along_axis(wts.lo, slot[..., None], axis=-1)[..., 0]
-    st["wts_seen_hi"] = jnp.where(rs_served, best_hi, st["wts_seen_hi"])
-    st["wts_seen_lo"] = jnp.where(rs_served, best_lo, st["wts_seen_lo"])
-    obs = jnp.maximum(
-        jnp.where(served, wts.hi.max(-1), 0).max(1), jnp.where(served, rts_obs, 0).max(1)
-    )
-    st["clock"] = jnp.maximum(st["clock"], obs)
-    w1 = _check_w1(store, st, served & st["is_w"])
-    bad = (rs_served & ~(found & r2)).any(1) | (served & st["is_w"] & ~w1).any(1)
-    return StageOut(st, store, fail=in_f & bad)
-
-
-MVCC = (
-    Stage(M_COMMIT, ST_COMMIT, ops=ops_write_set, effect=_mvcc_commit, done="commit", salt_off=1),
-    Stage(M_ABREL, ST_RELEASE, ops=ops_locked, effect=release_effect, done="abort",
-          next_stage=M_READ, new_ts=True, salt_off=2),
-    Stage(M_LOG, ST_LOG, kind=LOG, next_stage=M_COMMIT),
-    Stage(M_EXEC, ST_EXEC, kind=EXEC, next_stage=M_LOG),
-    Stage(M_LOCKW, ST_LOCK, ops=ops_lock_pending(True), effect=_mvcc_lock, next_stage=M_EXEC,
-          start_exec=True, retry_stage=M_READ, abrel_stage=M_ABREL, new_ts=True, salt_off=3),
-    Stage(M_RTS, ST_VALIDATE, ops=ops_read_set, effect=_mvcc_rts, ro_commit=True,
-          next_stage=M_LOCKW, retry_stage=M_READ, abrel_stage=M_ABREL, new_ts=True, salt_off=4),
-    Stage(M_READ, ST_FETCH, ops=ops_valid, effect=_mvcc_read, next_stage=M_RTS,
-          retry_stage=M_READ, abrel_stage=M_ABREL, new_ts=True, salt_off=5),
-)
-
-TICKS = {
-    "nowait": make_tick(NOWAIT, N_LOCK, salt_mult=17),
-    "mvcc": make_tick(MVCC, M_READ, salt_mult=37),
-}

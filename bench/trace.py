@@ -7,12 +7,13 @@ around one whole call (plan, execute, row handling).  Inside it:
   TPU core (``/device:TPU:<n>`` planes, ``XLA Ops`` line), averaged over
   the cores that ran anything;
 * each op's self time: its duration minus the ops nested inside it on the
-  same line, so self times add up to busy time;
+  same line, averaged over the cores like busy time, so self times add up
+  to busy time on four chips as on one;
 * Pallas kernels: the ops the compiler lowered from a ``pallas_call``,
   which the TPU trace names by their HLO text, a ``custom-call`` with
   ``custom_call_target="tpu_custom_call"`` (the kernels carry no name of
   their own, so one kernel is not told from another);
-* idle gaps: the stretches of the window in which the device ran nothing,
+* idle gaps: the stretches of the window in which no device ran anything,
   each put down to the innermost ``bench.*`` host span that covers its
   middle.
 """
@@ -52,7 +53,7 @@ class Trace:
 class Summary:
     window_s: float
     busy_s: float
-    op_self_s: Dict[str, float]  # op label -> self seconds, summed over devices
+    op_self_s: Dict[str, float]  # op label -> self seconds, averaged over devices
     pallas_s: float  # self seconds of Pallas kernels, averaged over devices
     n_devices: int
     idle_gaps: List[Tuple[str, float]]  # (host span, seconds), longest first
@@ -158,19 +159,20 @@ def reduce(trace: Trace, top_gaps: int = 10) -> Summary:
     op_self: Dict[str, float] = {}
     pallas_ns = 0.0
     busy_ns: List[float] = []
-    gaps: List[Interval] = []
+    ran: List[Interval] = []
     for events in trace.devices.values():
         busy = union([iv for ev in events if (iv := _clip(ev, lo, hi))])
         if not busy:
             continue
         busy_ns.append(sum(e - s for s, e in busy))
-        edges = [lo] + [x for iv in busy for x in iv] + [hi]
-        gaps += [(edges[i], edges[i + 1]) for i in range(0, len(edges), 2) if edges[i + 1] > edges[i]]
+        ran += busy
         for ev, ns in self_times(events, lo, hi):
             label = op_label(ev.name)
             op_self[label] = op_self.get(label, 0.0) + ns / 1e9
             if is_pallas(ev):
                 pallas_ns += ns
+    edges = [lo] + [x for iv in union(ran) for x in iv] + [hi]
+    gaps = [(edges[i], edges[i + 1]) for i in range(0, len(edges), 2) if edges[i + 1] > edges[i]]
     spans = [e for e in trace.host if e.name != WINDOW_SPAN]
 
     def doing(mid: float) -> str:
@@ -182,7 +184,7 @@ def reduce(trace: Trace, top_gaps: int = 10) -> Summary:
     return Summary(
         window_s=(hi - lo) / 1e9,
         busy_s=sum(busy_ns) / n / 1e9 if n else 0.0,
-        op_self_s=op_self,
+        op_self_s={k: v / n for k, v in op_self.items()},
         pallas_s=pallas_ns / n / 1e9 if n else 0.0,
         n_devices=n,
         idle_gaps=idle[:top_gaps],

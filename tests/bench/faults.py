@@ -1,9 +1,11 @@
 """Faults planted in the program for one tiny run of a cell.
 
 A step that returns its state unchanged, half of a grid's batch left out
-(its rows copied from the other half), and an answer altered where it is
-produced (every word the store gather returns).  The cells run on one
-chip, so no exchange between chips can be left out.
+(its rows copied from the other half), an answer altered where it is
+produced (every word the store gather returns), and, for a deployment laid
+out over several chips (the node layout), the exchange between chips left
+out: each shard keeps its own addend of a round's reply, so a read loses
+the owner's answer (tests/bench/test_bench_node.py).
 """
 import jax
 import jax.numpy as jnp
@@ -47,6 +49,13 @@ def gather_altered(mp):
 
     plain = engine.gather_rows
     mp.setattr(engine, "gather_rows", lambda arr, keys: plain(arr, keys) + 1)
+
+
+def exchange_dropped(mp):
+    from repro.core import planes, sweep
+
+    mp.setattr(planes, "_psum", lambda x, axis: x)
+    mp.setattr(sweep, "_NODE_RUNNERS", {})  # trace the node runner anew, with the fault
 
 
 def assert_not_correct(cell, fault, monkeypatch):

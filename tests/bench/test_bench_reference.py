@@ -28,3 +28,15 @@ def test_bfloat16_control_fails(cell):
     assert numbers["int_mismatch"] == 0
     assert numbers["float_gap"] > c.limits["float_gap"]
     assert not correct.judge(numbers, c.limits)
+
+
+def test_batched_rows_are_the_rows():
+    """A deployment laid over chips (``node_shards``, here one device): rows
+    computed ``NODE_BATCH`` at a time, the last batch filled up with a copy,
+    are the rows computed all at once on one device."""
+    from bench import reference
+
+    _, dep, tr = tiny("smallbank-nowait-grid64")
+    knobs = [{"hybrid": code, "seed": 500 + code} for code in (1, 22, 43)]  # 2 batches of 2
+    call = {"protocol": tr["protocol"], "ticks": tr["ticks"], "warmup": tr["warmup"]}
+    assert reference.rows(dict(dep, node_shards=1), call, knobs) == reference.rows(dep, call, knobs)
