@@ -12,31 +12,16 @@ import pytest
 
 from bench import harness, reference, traffic
 
+from cells import BENCH, CELLS, CELLS_ONE, assert_resolves
+
 ROOT = harness.ROOT
-BENCH = json.load(open(os.path.join(ROOT, "BENCHMARK.json")))
-CELLS = [w["name"] for w in BENCH["workloads"]]
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 
 
 @pytest.mark.parametrize("cell", CELLS)
 def test_cell_resolves_by_name(cell):
-    c = harness.load_cell(cell, BENCH)
-    # the reference has a generator for the workload and a tick for the protocol
-    assert callable(reference.workload(c.deployment["workload"]))
-    assert callable(reference.protocol(c.traffic["protocol"]).tick)
-    traffic.validate(c.traffic)
-    assert set(c.limits) == {"int_mismatch", "float_gap"} and c.sample_rows >= 1
-    names = {m["name"] for m in c.end_to_end}
-    assert "setup_s" in names and len(names) >= 2
-    run = harness.Run(c.deployment, c.traffic, "cpu", setup_s=1.5, window_s=2.0, config_ticks=10)
-    got = harness.end_to_end(run, c.end_to_end)
-    assert set(got) == names and got["setup_s"]["value"] == 1.5
-    assert sorted(v["value"] for k, v in got.items() if k != "setup_s") == [5.0] * (len(names) - 1)
-    assert c.per_layer, "every cell reports a per-layer metric"
-    for m in c.per_layer:
-        assert callable(harness.metric_reader(m["name"]))
-        assert m["moves"] in names, (m["name"], m["moves"])
+    assert_resolves(harness.load_cell(cell, BENCH))
 
 
 @pytest.mark.parametrize("kind", ["protocol", "workload"])
@@ -81,7 +66,7 @@ def _spec_as_it_was(api, dep, call):
     )
 
 
-@pytest.mark.parametrize("cell", CELLS)
+@pytest.mark.parametrize("cell", CELLS_ONE)
 def test_spec_for_is_unchanged(cell):
     from repro import api
 
